@@ -137,7 +137,7 @@ def child_serve(spec):
 
     n_pre, n_dec = spec["prefill"], spec["decode"]
     roles = ["prefill"] * n_pre + ["decode" if n_pre else "both"] * n_dec
-    pc_on = host_budgeting.enable_compile_cache(spec["cache_dir"])
+    host_budgeting.enable_compile_cache(spec["cache_dir"])
     budgets = host_budgeting.compute_pool_budgets(
         {"prefill": n_pre, "decode": n_dec}) if n_pre else \
         {"both": host_budgeting.compute_host_budget(n_dec)}
@@ -239,8 +239,7 @@ def child_serve(spec):
                 1e3 * wait_s / handoffs, 2) if handoffs else 0.0,
             "prewarm_s": round(prewarm_s, 2),
             "prewarm_variants": sum(r["variants"] for r in prewarm),
-            "persistent_cache": dict(persistent_cache_counters()) if pc_on
-            else None,
+            "persistent_cache": dict(persistent_cache_counters()),
             "per_engine": [{
                 "role": roles[i],
                 "requests": s["requests"],

@@ -139,7 +139,7 @@ def child_engines(spec):
     from repro.server import EngineLoop, EngineRouter, HttpFrontend
 
     n = spec["engines"]
-    pc_on = host_budgeting.enable_compile_cache(spec["cache_dir"])
+    host_budgeting.enable_compile_cache(spec["cache_dir"])
     budget = host_budgeting.compute_host_budget(n)
 
     cfg = get_config("tiny")
@@ -183,8 +183,7 @@ def child_engines(spec):
             "latency_p99_ms": round(1e3 * percentile(lat, 99), 1),
             "prewarm_s": round(prewarm_s, 2),
             "prewarm_variants": sum(r["variants"] for r in prewarm),
-            "persistent_cache": dict(persistent_cache_counters()) if pc_on
-            else None,
+            "persistent_cache": dict(persistent_cache_counters()),
             "per_engine": [{
                 "requests": s["requests"],
                 "decode_busy_s": round(s["busy_time_s"], 3),

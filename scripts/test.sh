@@ -197,9 +197,10 @@ run_smoke() {
 run_kernels() {
     # kernel-parity sweeps + fused-loop identity tests, then a fused
     # continuous-serve smoke with attention/confidence routed through
-    # the Pallas kernels (interpret mode on CPU; real lowering on TPU
-    # with REPRO_PALLAS_INTERPRET=0)
-    python -m pytest -x -q tests/test_kernels.py tests/test_fused_decode.py
+    # the Pallas kernels (interpret mode on CPU, compiled on TPU; the
+    # TPU compiles at real widths are in tests/test_tpu_compile.py)
+    python -m pytest -x -q tests/test_kernels.py tests/test_tpu_compile.py \
+        tests/test_fused_decode.py
     echo "== smoke: repro.launch.serve --mode continuous --use-kernels =="
     python -m repro.launch.serve --arch tiny --n 4 --mode continuous \
         --train-steps 120 --max-slots 4 --use-kernels

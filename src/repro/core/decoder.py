@@ -1053,6 +1053,31 @@ class DiffusionDecoder:
                                      donate_argnums=donate)
         return self._fns["fused"]
 
+    def _fused_inputs(self, state: DecodeState, region, qpos):
+        """Device arguments and static kwargs of the fused fn for the
+        next block of ``state``."""
+        qpos_b = np.broadcast_to(qpos[None], (state.batch, len(qpos))).copy()
+        vm = None if state.valid_mask is None \
+            else self._put_batch(state.valid_mask)
+        cm = None if state.cached_mask is None \
+            else self._put_batch(state.cached_mask)
+        args = (self.params, self._put_batch(state.x),
+                self._put_batch(state.committed),
+                self._put_batch(state.done), state.cache,
+                self._put_batch(qpos_b), vm, cm)
+        static = dict(bstart=region.block_start,
+                      pstart=state.prompt_len if self.dcfg.prefix_cache
+                      else 0)
+        return args, static
+
+    def lower_block(self, state: DecodeState):
+        """AOT-lower the fused fn for the next block of ``state`` without
+        running it — to inspect the compiled block program (which
+        kernels it calls, its memory)."""
+        region, qpos = self._query_region(state)
+        args, static = self._fused_inputs(state, region, qpos)
+        return self._fused_fn().lower(*args, **static)
+
     def _decode_block_fused(self, state: DecodeState) -> DecodeState:
         d = self.dcfg
         t_block = time.perf_counter()
@@ -1064,22 +1089,13 @@ class DiffusionDecoder:
 
         region, qpos = self._query_region(state)
         Sq = len(qpos)
-        qpos_b = np.broadcast_to(qpos[None], (B, Sq)).copy()
-        bstart = region.block_start
-        prefix_len = bstart
+        prefix_len = region.block_start
 
         live_rows = int((~state.done).sum())
-        vm = None if state.valid_mask is None \
-            else self._put_batch(state.valid_mask)
-        cm = None if state.cached_mask is None \
-            else self._put_batch(state.cached_mask)
+        args, static = self._fused_inputs(state, region, qpos)
         (x, committed, done, steps, n_hit, cache, vm, cm,
          vsums, counts, hist, fill_n, cconf) = self._fused_fn()(
-            self.params, self._put_batch(state.x),
-            self._put_batch(state.committed), self._put_batch(state.done),
-            state.cache, self._put_batch(qpos_b),
-            vm, cm, bstart=bstart,
-            pstart=P if d.prefix_cache else 0)
+            *args, **static)
 
         # the ONE host sync for this block (np.array: writable copies —
         # the scheduler and finalize mutate these buffers in place).

@@ -11,17 +11,29 @@ tile it explicitly for VMEM:
   k/v tile (TK, D) VMEM
   online-softmax scratch: acc (TQ, D) f32, m/l (TQ, 1) f32
 
+Layout (TPU tiling: the last two dims of every block must be multiples
+of (8, 128) or span the whole array dim). q/k/v are viewed as
+``(B, S, H*D)`` — a free reshape — and one head is the block
+``(1, T, D)`` at column-block ``h``, so the head dim must be a multiple
+of 128 on TPU (LLaDA-8B and Dream-7B use 128). Query positions travel
+as ``(B, Sq, 1)`` (a ``(TQ, 1)`` column per tile), key positions and
+the key-validity mask as ``(B, 1, Skv)`` (a ``(1, TK)`` row); the mask
+is int32 because Mosaic has no bool memrefs.
+
 Features folded into the same kernel (all static): GQA head mapping,
 attention-logit softcap (gemma2), sliding-window masking (local layers /
 long_500k dense variant), and arbitrary KV validity (growing caches and
 the dKV position-indexed cache).
 
-Validated on CPU with interpret=True against ref.block_attention_ref.
+The kernel runs compiled on TPU and in interpret mode on every other
+backend (``interpret=None`` picks from ``jax.default_backend()`` at
+trace time); the CPU tests validate it against
+``ref.block_attention_ref``.
 """
 from __future__ import annotations
 
 import functools
-import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,9 +46,25 @@ NEG_INF = -1e30
 M_CLAMP = -1e4
 
 
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Interpret mode unless the kernel is being traced for a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+def matmul_precision():
+    """In-kernel dot precision following the caller's
+    ``jax.default_matmul_precision`` scope, so a float32 reference run
+    under "highest" also gets full-precision MXU passes in the kernel."""
+    if jax.config.jax_default_matmul_precision in ("highest", "float32"):
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 def _kernel(q_ref, k_ref, v_ref, qpos_ref, kvpos_ref, kvmask_ref,
             o_ref, acc_ref, m_ref, l_ref, *, scale, softcap, window,
-            n_kv_tiles):
+            n_kv_tiles, precision):
     j = pl.program_id(3)
 
     @pl.when(j == 0)
@@ -45,18 +73,19 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kvpos_ref, kvmask_ref,
         m_ref[...] = jnp.full_like(m_ref, M_CLAMP)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale       # (TQ, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # (TK, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)               # (TK, D)
+    q = q_ref[0].astype(jnp.float32) * scale                # (TQ, D)
+    k = k_ref[0].astype(jnp.float32)                        # (TK, D)
+    v = v_ref[0].astype(jnp.float32)                        # (TK, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (TQ, TK)
+                            preferred_element_type=jnp.float32,
+                            precision=precision)            # (TQ, TK)
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
 
-    mask = kvmask_ref[0, :][None, :]                        # (1, TK)
+    mask = kvmask_ref[0] != 0                               # (1, TK)
     if window:
-        qp = qpos_ref[0, :][:, None]                        # (TQ, 1)
-        kp = kvpos_ref[0, :][None, :]                       # (1, TK)
+        qp = qpos_ref[0]                                    # (TQ, 1)
+        kp = kvpos_ref[0]                                   # (1, TK)
         mask = mask & (jnp.abs(qp - kp) <= window)
     s = jnp.where(mask, s, NEG_INF)
 
@@ -67,21 +96,22 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kvpos_ref, kvmask_ref,
     correction = jnp.exp(m_prev - m_new)                    # (TQ, 1)
     l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=1, keepdims=True)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32,
+                             precision=precision)
     acc_ref[...] = acc_ref[...] * correction + pv
     m_ref[...] = m_new
 
     @pl.when(j == n_kv_tiles - 1)
     def _finalize():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "window",
                                              "tq", "tk", "interpret"))
 def block_attention(q, k, v, q_pos, kv_pos, kv_mask, *, scale,
                     softcap: float = 0.0, window: int = 0, tq: int = 128,
-                    tk: int = 128, interpret: bool = True):
+                    tk: int = 128, interpret: Optional[bool] = None):
     """q: (B, Sq, H, D); k/v: (B, Skv, Hkv, D); masks per ref.py.
 
     Returns (B, Sq, H, D) f32. Pads Sq/Skv to tile multiples internally.
@@ -105,25 +135,31 @@ def block_attention(q, k, v, q_pos, kv_pos, kv_mask, *, scale,
 
     grid = (B, H, nq, nk)
     kernel = functools.partial(_kernel, scale=scale, softcap=softcap,
-                               window=window, n_kv_tiles=nk)
+                               window=window, n_kv_tiles=nk,
+                               precision=matmul_precision())
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, tk, 1, D), lambda b, h, i, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, tk, 1, D), lambda b, h, i, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, tq), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, tk), lambda b, h, i, j: (b, j)),
-            pl.BlockSpec((1, tk), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((1, tq, D), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, tk, D), lambda b, h, i, j: (b, j, h // g)),
+            pl.BlockSpec((1, tk, D), lambda b, h, i, j: (b, j, h // g)),
+            pl.BlockSpec((1, tq, 1), lambda b, h, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, tk), lambda b, h, i, j: (b, 0, j)),
+            pl.BlockSpec((1, 1, tk), lambda b, h, i, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, tq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H, D), jnp.float32),
+        out_specs=pl.BlockSpec((1, tq, D), lambda b, h, i, j: (b, i, h)),
+        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H * D), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((tq, D), jnp.float32),
             pltpu.VMEM((tq, 1), jnp.float32),
             pltpu.VMEM((tq, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(q, k, v, q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32), kv_mask)
-    return out[:, :Sq]
+        interpret=resolve_interpret(interpret),
+        name="block_attention",
+    )(q.reshape(B, Sq_p, H * D), k.reshape(B, Skv_p, Hkv * D),
+      v.reshape(B, Skv_p, Hkv * D),
+      q_pos.astype(jnp.int32)[:, :, None],
+      kv_pos.astype(jnp.int32)[:, None, :],
+      kv_mask.astype(jnp.int32)[:, None, :])
+    return out.reshape(B, Sq_p, H, D)[:, :Sq]

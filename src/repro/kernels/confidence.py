@@ -10,16 +10,21 @@ vocab tiles through VMEM once, tracking running (max, sum-exp, argmax):
   grid = (nS, nV)  -- vocab tiles innermost/sequential
   logits tile (TS, TV) VMEM; scratch m/s (TS,1) f32, amax (TS,1) i32
 
-Validated with interpret=True against ref.confidence_argmax_ref.
+Runs compiled on TPU and in interpret mode elsewhere (see
+``block_attention.resolve_interpret``); validated on CPU against
+ref.confidence_argmax_ref.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.block_attention import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -53,7 +58,7 @@ def _kernel(x_ref, conf_ref, idx_ref, m_ref, s_ref, a_ref, *, n_v_tiles, tv):
 
 @functools.partial(jax.jit, static_argnames=("ts", "tv", "interpret"))
 def confidence_argmax(logits, *, ts: int = 128, tv: int = 512,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """logits: (N, V) -> (conf (N,) f32, idx (N,) i32)."""
     N, V = logits.shape
     ts = min(ts, max(8, 1 << (N - 1).bit_length()))
@@ -78,6 +83,7 @@ def confidence_argmax(logits, *, ts: int = 128, tv: int = 512,
         scratch_shapes=[pltpu.VMEM((ts, 1), jnp.float32),
                         pltpu.VMEM((ts, 1), jnp.float32),
                         pltpu.VMEM((ts, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="confidence_argmax",
     )(x)
     return conf[:N, 0], idx[:N, 0]

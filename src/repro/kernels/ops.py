@@ -1,26 +1,34 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (CPU validation container); on real TPU
-set REPRO_PALLAS_INTERPRET=0.
+The kernels run compiled on TPU and in interpret mode on any other
+backend, picked at trace time; pass ``interpret=`` to override (the
+TPU compile tests pass ``False`` while tracing on a CPU host).
 """
 from __future__ import annotations
 
 import math
-import os
+import re
 
 import jax.numpy as jnp
 
 from repro.kernels.block_attention import block_attention as _block_attention
 from repro.kernels.confidence import confidence_argmax as _confidence_argmax
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+
+_KERNEL_CALL = re.compile(r"%([A-Za-z_]+)(?:\.\d+)? = .*tpu_custom_call")
+
+
+def compiled_kernels(hlo_text: str) -> set:
+    """Names of the Pallas kernels a compiled TPU program calls (each
+    ``pallas_call`` is named after its kernel). An interpreted kernel
+    lowers to plain HLO and never shows up here."""
+    return {m.group(1) for m in _KERNEL_CALL.finditer(hlo_text)}
 
 
 def block_attention(q, k, v, q_pos, kv_pos, kv_mask, *, scale=None,
                     softcap: float = 0.0, window: int = 0, **kw):
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    kw.setdefault("interpret", INTERPRET)
     return _block_attention(q, k, v, q_pos, kv_pos, kv_mask, scale=scale,
                             softcap=softcap, window=window, **kw)
 
@@ -40,7 +48,6 @@ def confidence_argmax(logits, **kw):
     2-D inputs (the fused-head path feeds row chunks) go straight to the
     kernel — no intermediate full-vocab reshape of an array that is
     already in kernel layout."""
-    kw.setdefault("interpret", INTERPRET)
     if logits.ndim == 2:
         return _confidence_argmax(logits, **kw)
     shape = logits.shape[:-1]

@@ -106,15 +106,6 @@ def SHAPE_KIND(shape_name: str) -> str:
     return SHAPES[shape_name]["kind"]
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on jax >= 0.6 but a
-    one-element list of dicts on the 0.4.x line — normalize."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def run_one(arch: str, shape_name: str, multi_pod: bool,
             variant: str = "streaming", out_dir: str = "results/dryrun",
             mesh_dims=None, unroll: int = 1):
@@ -143,7 +134,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
                           out_shardings=spec.out_shardings).lower(*spec.args)
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     promo = parse_cpu_promotion_bytes(hlo)
@@ -210,7 +201,7 @@ def _compile_stats(cfg, mesh, shape_name, variant):
                            out_shardings=spec.out_shardings) \
             .lower(*spec.args).compile()
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)),

@@ -10,13 +10,12 @@ item 1). The fix is to *budget*: size the pool to one engine's share of
 the host, derived as ``cores // engines`` and overridable with
 ``--host-threads-per-engine``.
 
-Mechanics, for the jaxlib this repo pins (0.4.x):
+Mechanics:
 
 * ``PJRT_NPROC`` — read by XLA's ``DefaultThreadPoolSize()`` when the
   CPU PjRt client is created; sizes the Eigen intra-op pool and the
-  client's async work pool. This is the effective intra-op knob (the
-  classic ``intra_op_parallelism_threads`` XLA_FLAGS spelling is
-  rejected by this jaxlib's flag parser).
+  client's async work pool. This is the effective intra-op knob (XLA's
+  flag parser has no intra-op thread-count flag).
 * ``--xla_cpu_multi_thread_eigen=false`` — appended when the budget is
   a single thread, so legacy Eigen paths can't spawn their own workers.
 * inter-op parallelism needs no flag here: the N decode threads *are*
@@ -144,31 +143,23 @@ def budget_env(budget: Optional[HostBudget] = None, *,
     return env
 
 
-def enable_compile_cache(cache_dir: str) -> bool:
+def enable_compile_cache(cache_dir: str) -> None:
     """Wire JAX's persistent compilation cache at ``cache_dir`` and
     start counting its hit/miss events (``repro.obs.compile``). Safe to
-    call after jax import (it uses ``jax.config``, not env); returns
-    False when this jax build has no persistent cache support."""
+    call after jax import (it uses ``jax.config``, not env). Raises on
+    any failure: a server that silently runs without the cache pays
+    every compile again on each start."""
     if not cache_dir:
-        return False
+        raise ValueError("enable_compile_cache needs a directory")
     import jax
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache everything — the fused per-block fns are exactly the
-        # small-but-hot compiles the default min-time threshold skips
-        for knob, val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(knob, val)
-            except (AttributeError, Exception):
-                pass
-    except Exception:
-        return False
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # cache everything — the fused per-block fns are exactly the
+    # small-but-hot compiles the default min-time threshold skips
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     from repro.obs.compile import watch_persistent_cache
     watch_persistent_cache()
-    return True
 
 
 def _append_xla_flags(flag: str) -> None:

@@ -11,18 +11,10 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6 names mesh axis kinds explicitly; older jax (the CI
-    # image ships 0.4.x) predates AxisType and treats every axis as
-    # what AxisType.Auto means, so omitting the kwarg is equivalent.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

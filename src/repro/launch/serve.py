@@ -109,7 +109,7 @@ def main():
                     help="print per-block chunks as they commit")
     ap.add_argument("--use-kernels", action="store_true",
                     help="route attention/confidence through the Pallas "
-                         "kernels (REPRO_PALLAS_INTERPRET=0 on real TPU)")
+                         "kernels (compiled on TPU, interpreted elsewhere)")
     ap.add_argument("--host-loop", action="store_true",
                     help="legacy per-step host denoise loop instead of "
                          "the fused device-resident loop")
@@ -291,11 +291,8 @@ def main():
     import jax
 
     if args.compile_cache_dir:
-        if host_budgeting.enable_compile_cache(args.compile_cache_dir):
-            print(f"persistent compile cache at {args.compile_cache_dir}")
-        else:
-            print("persistent compile cache unsupported by this jax "
-                  "build; continuing without")
+        host_budgeting.enable_compile_cache(args.compile_cache_dir)
+        print(f"persistent compile cache at {args.compile_cache_dir}")
     print(f"host budget: {budget.describe()}")
     if pool_budgets is not None:
         for role in ("prefill", "decode"):
@@ -321,8 +318,8 @@ def main():
 
     cfg = get_config(args.arch, block_size=8)
     if args.ckpt:
-        params = checkpoint.restore(args.ckpt,
-                                    init_params(cfg, jax.random.PRNGKey(0)))
+        params = checkpoint.restore(args.ckpt, jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0))))
     else:
         params, _ = train(cfg, TrainConfig(steps=args.train_steps,
                                            batch_size=32, seq_len=44))
@@ -333,13 +330,19 @@ def main():
                      cache_chunk=args.cache_chunk)
     tok = ByteTokenizer(cfg.vocab_size)
 
-    # placement: one DecodeExecutor per engine submesh (None = today's
+    # placement: one DecodeExecutor per engine submesh (None = the
     # single-device path); params are placed per mesh, caches are born
-    # sharded, gang batches shard over the data axis
+    # sharded, gang batches shard over the data axis. Several engines
+    # on a host with enough devices get one device each even without
+    # --mesh. The params go through host memory first, so each device
+    # holds exactly one copy (the default device none extra).
     executors = [None] * args.engines
+    if mesh_dims is None and 1 < args.engines <= len(jax.devices()):
+        mesh_dims = (1, 1)
     if mesh_dims is not None:
         from repro.launch.mesh import make_submeshes
         from repro.serving import DecodeExecutor
+        params = jax.device_get(params)
         executors = [DecodeExecutor(cfg, params, m)
                      for m in make_submeshes(args.engines, *mesh_dims)]
 
@@ -373,7 +376,8 @@ def main():
                     max_bytes=args.cache_bytes,
                     placement=ex.placement if ex is not None
                     else HOST_PLACEMENT)
-        return ContinuousEngine(cfg, params, d, max_slots=args.max_slots,
+        return ContinuousEngine(cfg, params if ex is None else ex.params,
+                                d, max_slots=args.max_slots,
                                 tokenizer=tok, executor=ex,
                                 prefix_cache=store,
                                 prefill_only=(role == "prefill"),

@@ -27,13 +27,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import _dense_init
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:  # jax 0.4.x: experimental namespace, check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 
 def init_moe(key, cfg, dtype) -> dict:
     d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
@@ -261,11 +254,11 @@ def apply_moe_ep(cfg, p, x, mesh, *, data_axes=("data",), model_axis="model"):
         aux = cfg.n_experts * jnp.sum(f_e * p_e)
         return y.reshape(x_l.shape), aux, drop
 
-    y, aux, drop = _shard_map(
+    y, aux, drop = jax.shard_map(
         local, mesh=mesh,
         in_specs=(batch_spec, pspec),
         out_specs=(batch_spec, P(), P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(x, p)
     return y, aux
 
@@ -298,11 +291,11 @@ def apply_moe_ep_replicated(cfg, p, x, mesh, *, ep_axis="data",
         aux = load_balance_loss(cfg, probs, ids)
         return y.reshape(x_l.shape).astype(x_l.dtype), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None, None), pspec),
         out_specs=(P(None, None, None), P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(x, p)
     return y, aux
 

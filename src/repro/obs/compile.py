@@ -109,19 +109,14 @@ def _on_event(event: str, **kw) -> None:
             _pc_counters["misses"] += 1
 
 
-def watch_persistent_cache() -> bool:
-    """Register the jax monitoring listener (idempotent). Returns False
-    when this jax build exposes no monitoring hooks."""
+def watch_persistent_cache() -> None:
+    """Register the jax monitoring listener (idempotent)."""
     global _pc_registered
     if _pc_registered:
-        return True
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-    except Exception:
-        return False
+        return
+    from jax._src import monitoring
+    monitoring.register_event_listener(_on_event)
     _pc_registered = True
-    return True
 
 
 def persistent_cache_counters() -> dict:

@@ -401,6 +401,9 @@ class HttpFrontend:
     def _completion_json(self, comp, ticket: Ticket) -> dict:
         doc = {
             "uid": comp.uid, "text": comp.text,
+            # the byte tokenizer's text drops every id past the bytes,
+            # so the ids are the only lossless output of a real vocab
+            "tokens": [int(t) for t in comp.tokens],
             "n_tokens": comp.n_tokens, "n_blocks": comp.n_blocks,
             "max_tokens": comp.max_tokens,
             "finish_reason": finish_reason(comp, ticket.cancel_reason),

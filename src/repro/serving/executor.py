@@ -51,7 +51,7 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import data_axes_of
 from repro.launch.sharding import SpecBuilder
 from repro.models.config import ModelConfig
-from repro.models.model import init_cache
+from repro.models.model import init_cache, init_params
 
 
 class DecodeExecutor:
@@ -73,9 +73,24 @@ class DecodeExecutor:
         self._sb = SpecBuilder(cfg, mesh, mode="serve")
         self._dp = (self.data_axes if len(self.data_axes) > 1
                     else (self.data_axes[0] if self.data_axes else None))
+        # a no-op for params already placed this way (random_init)
         self.params = jax.device_put(params, self._shardings(
             self._sb.params()))
         self._cache_fns: Dict[Tuple[int, int], Any] = {}
+
+    @classmethod
+    def random_init(cls, cfg: ModelConfig, mesh, seed: int = 0,
+                    **kw) -> "DecodeExecutor":
+        """Executor over seeded random weights created *on its mesh*:
+        one jitted ``init_params`` with the placement as
+        ``out_shardings``, so no other device ever holds a copy. Same
+        seed, same weights on every mesh."""
+        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 SpecBuilder(cfg, mesh, mode="serve").params(),
+                                 is_leaf=lambda x: isinstance(x, P))
+        params = jax.jit(lambda key: init_params(cfg, key),
+                         out_shardings=shardings)(jax.random.PRNGKey(seed))
+        return cls(cfg, params, mesh, **kw)
 
     # ------------------------------------------------------ identity
 

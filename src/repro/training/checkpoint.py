@@ -31,11 +31,14 @@ def save(path: str, tree: Any, metadata: dict | None = None) -> None:
 
 
 def restore(path: str, like: Any) -> Any:
+    """Host (numpy) arrays shaped like ``like``, which may hold arrays or
+    ``jax.ShapeDtypeStruct``s (``jax.eval_shape`` of an init: no device
+    copy is made just to describe the tree)."""
     leaves, treedef = _flatten(like)
     with np.load(path + ".npz") as z:
         loaded = [z[f"leaf_{i}"] for i in range(len(leaves))]
     assert len(loaded) == len(leaves), "checkpoint/model structure mismatch"
-    cast = [np.asarray(a, dtype=np.asarray(l).dtype) if a.dtype != np.asarray(l).dtype else a
+    cast = [a.astype(l.dtype) if a.dtype != l.dtype else a
             for a, l in zip(loaded, leaves)]
     for a, l in zip(cast, leaves):
         assert a.shape == l.shape, f"shape mismatch {a.shape} vs {l.shape}"

@@ -17,7 +17,7 @@ import numpy as np
 from repro.launch import host as host_budgeting
 
 CACHE_DIR = sys.argv[1]
-PC_ON = host_budgeting.enable_compile_cache(CACHE_DIR)
+host_budgeting.enable_compile_cache(CACHE_DIR)
 
 import jax  # noqa: E402  (cache config must precede first compile)
 
@@ -82,7 +82,6 @@ def main():
 
     print(json.dumps({
         "n_devices": len(jax.devices()),
-        "persistent_cache": PC_ON,
         "pjrt_nproc": budget.intra_op,
         "per_engine": per_engine,
     }))

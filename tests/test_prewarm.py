@@ -108,5 +108,4 @@ def test_budget_and_cache_reach_the_engines(tmp_path_factory):
     for e in rep["per_engine"]:
         assert e["host_threads"] == rep["pjrt_nproc"] >= 1
         assert e["compile_misses"] >= e["prewarm_variants"]
-    if rep["persistent_cache"]:   # this jax build has the cache
-        assert rep["cache_entries"] > 0
+    assert rep["cache_entries"] > 0
