@@ -3,22 +3,22 @@
 Drives the stack a user runs — ``HttpFrontend -> EngineLoop ->
 ContinuousEngine -> DecodeExecutor`` — over loopback HTTP with seeded
 random weights at the published LLaDA-8B widths (d_model 4096, 32 heads
-x 128, d_ff 12288, vocab 126,464, SwiGLU, block 32, float32). Depth is
-cut to 6 of 32 layers: at 8 layers the fused block program does not fit
-16 GB, because XLA:TPU keeps a bf16 copy of every weight matrix next to
-the float32 params. Requests use the ``streaming`` method, the fused
-denoise loop and the compiled Pallas kernels.
+x 128, d_ff 12288, vocab 126,464, SwiGLU, block 32, float32), with the
+depth cut to ``N_LAYERS`` of 32. Requests use the ``streaming`` method,
+the fused denoise loop and the compiled Pallas kernels.
 
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # 4 engines, one per chip, vs 1
 
-One chip: kernel-vs-reference logits at full width, then >= 8
+One chip: kernel-vs-reference logits at full width (and a control with
+the attention inputs in bf16, which must fail the bound), the gang
+witness (a row's logits alone and inside a padded gang of 4), then >= 8
 concurrent HTTP requests (256-token prompts, max_tokens 256), then the
 serving invariants (one host sync per block, no compile after pre-warm,
 both kernels compiled into the block program). Four chips: the same
 requests served by one engine and by four engines behind the router,
-each request decoding alone; every chip must serve, tokens must match,
-and each chip must hold one copy of the params.
+every gang padded to 4 rows on both sides; every chip must serve,
+tokens must match, and each chip must hold one copy of the params.
 
 The figures printed on the way are smoke figures, not benchmark
 numbers. The last line of stdout is ``{"ok": true, "device": {...}}``;
@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import json
 import os
 import sys
@@ -43,25 +44,29 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 ARCH = "llada-8b"
+# The TPU compiler refuses the 8-layer batch-8 block program (16.64 GiB
+# of 15.75 GiB HBM): at the default matmul precision it keeps a bf16
+# copy of every weight as temp next to the float32 params.
 N_LAYERS = 6
 PROMPT_LEN = 256
 MAX_TOKENS = 256
 N_REQUESTS = 8
 MAX_SLOTS = 8
 # --chips 4: a shorter generation keeps the four engines' pre-warm
-# (compiled once per chip) inside the time limit. Each request decodes
-# alone: on TPU a request's tokens depend on the size of the gang it
-# decodes in (5 of 8 requests matched between gangs of up to 4 and
-# gangs of 2), so only equal gang shapes make the runs comparable.
+# (compiled once per chip) inside the time limit. Every gang is padded
+# to FLEET_GANG rows on both sides: on the TPU a row's logits depend on
+# the gang's size at the rounding level (the gang witness measures it),
+# so tokens can only be compared between runs with equal gang shapes.
+# Within one shape, a row's tokens must not depend on its gang mates.
 FLEET_MAX_TOKENS = 64
-FLEET_MAX_SLOTS = 1
+FLEET_GANG = 4
 SERVE_TIMEOUT_S = 600.0
 # Kernel vs reference logits, both under float32 matmuls: relative L2
 # error ||kernel - ref|| / ||ref||. The two differ only in summation
-# order (online softmax over KV tiles), ~1e-6 per op and ~1e-5 after
-# the stack. Attention in bf16 rounds q, k and v to 8 mantissa bits
-# (relative 4e-3 per element), which lands at ~1e-3 on the logits and
-# fails this bound.
+# order (online softmax over KV tiles), ~1e-6 on the chip. Rounding the
+# kernel's q, k and v to bf16 (8 mantissa bits, everything else
+# float32) must land above the bound: kernel_parity measures that
+# control and run_one_chip checks it.
 LOGIT_RTOL = 1e-4
 KERNELS = ("block_attention", "confidence_argmax")
 
@@ -75,9 +80,9 @@ def check(cond: bool, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-def model_config(n_layers: int = N_LAYERS):
+def model_config(n_layers: int = None):
     from repro.models import get_config
-    return get_config(ARCH, n_layers=n_layers, reps=0)
+    return get_config(ARCH, n_layers=n_layers or N_LAYERS, reps=0)
 
 
 def make_prompts(n: int, length: int, seed: int) -> list:
@@ -88,17 +93,21 @@ def make_prompts(n: int, length: int, seed: int) -> list:
             for _ in range(n)]
 
 
-def make_engine(cfg, executor, max_tokens: int, max_slots: int):
+def make_engine(cfg, executor, max_tokens: int, max_slots: int,
+                batch_multiple: int = None):
     from repro.core.decoder import DecodeConfig
     from repro.data.tokenizer import ByteTokenizer
     from repro.serving import ContinuousEngine, PrefixKVPool
     dcfg = DecodeConfig(method="streaming", gen_len=max_tokens,
                         block_size=cfg.block_size, use_kernels=True)
     # pad_pow2: gang sizes 1/2/4/8 only, so pre-warm covers every
-    # shape admission, compaction and merges can produce. One free KV
-    # buffer kept for reuse: the chip has no room to park one per size.
+    # shape admission, compaction and merges can produce; a
+    # batch_multiple of max_slots pads every gang to one size. One free
+    # KV buffer kept for reuse: the chip has no room to park one per
+    # size.
     return ContinuousEngine(cfg, executor.params, dcfg,
                             max_slots=max_slots, pad_pow2=True,
+                            batch_multiple=batch_multiple,
                             executor=executor,
                             pool=PrefixKVPool(cfg, max_free=1,
                                               executor=executor),
@@ -121,15 +130,32 @@ def _rel_err(a, b) -> float:
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
-def kernel_parity(cfg, params, prompt_len: int = PROMPT_LEN,
-                  gen_len: int = MAX_TOKENS, batch: int = 2,
-                  seed: int = 0) -> dict:
-    """One prefill and one denoise-step forward (``apply_model``) with
-    the Pallas kernels and with the jnp reference on the same inputs;
-    returns the relative L2 error of the logits. Both run under float32
-    matmuls; ``step_default_precision`` is the kernel step at the
-    backend's default matmul precision (bf16 passes on TPU), printed to
-    show what the bound rejects."""
+@contextlib.contextmanager
+def _attention_inputs_in_bf16(on: bool):
+    """While tracing, round the attention kernel's q, k and v to bf16
+    (and back): the control that ``LOGIT_RTOL`` must reject."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    real = ops.block_attention
+    if on:
+        def rnd(x):
+            return x.astype(jnp.bfloat16).astype(x.dtype)
+
+        def rounded(q, k, v, *args, **kw):
+            return real(rnd(q), rnd(k), rnd(v), *args, **kw)
+        ops.block_attention = rounded
+    try:
+        yield
+    finally:
+        ops.block_attention = real
+
+
+def _forwards(cfg, prompt_len: int, gen_len: int):
+    """Jitted prefill and first denoise step of the streaming method
+    (``apply_model``): ``prefill(params, toks, uk) -> (logits, cache)``
+    and ``step(params, cache, batch, uk, bf16_attention) -> logits``;
+    ``uk`` routes attention to the Pallas kernel."""
     import functools
 
     import jax
@@ -139,42 +165,103 @@ def kernel_parity(cfg, params, prompt_len: int = PROMPT_LEN,
     from repro.core.suffix import suffix_query_region
     from repro.models.model import apply_model, init_cache
 
-    B, P, T = batch, prompt_len, prompt_len + gen_len
+    P, T = prompt_len, prompt_len + gen_len
     d = DecodeConfig(method="streaming", gen_len=gen_len,
                      block_size=cfg.block_size)
     region = suffix_query_region(
         gen_start=P, gen_len=gen_len, block_size=cfg.block_size,
         block_idx=0, window=d.effective_window)
-    rng = np.random.default_rng(seed)
-    toks = jnp.asarray(rng.integers(0, 256, (B, P)), jnp.int32)
-    Sq = len(region.positions)
-    qpos = jnp.broadcast_to(jnp.asarray(region.positions, jnp.int32)[None],
-                            (B, Sq))
-    qtoks = jnp.full((B, Sq), cfg.mask_token_id, jnp.int32)
+    positions = np.asarray(region.positions, np.int32)
 
     @functools.partial(jax.jit, static_argnames="uk")
     def prefill(p, toks, uk):
         out = apply_model(cfg, p, tokens=toks, mode="encode",
-                          cache=init_cache(cfg, B, T), use_kernels=uk)
+                          cache=init_cache(cfg, toks.shape[0], T),
+                          use_kernels=uk)
         return out.logits, out.cache
 
-    @functools.partial(jax.jit, static_argnames="uk")
-    def step(p, cache, uk):
-        return apply_model(cfg, p, tokens=qtoks, positions=qpos,
-                           mode="step", cache=cache,
-                           kv_valid=jnp.full((B,), P, jnp.int32),
-                           use_kernels=uk).logits
+    @functools.partial(jax.jit,
+                       static_argnames=("batch", "uk", "bf16_attention"))
+    def step(p, cache, batch, uk, bf16_attention=False):
+        qpos = jnp.broadcast_to(jnp.asarray(positions)[None],
+                                (batch, len(positions)))
+        with _attention_inputs_in_bf16(bf16_attention):
+            return apply_model(
+                cfg, p, tokens=jnp.full(qpos.shape, cfg.mask_token_id,
+                                        jnp.int32),
+                positions=qpos, mode="step", cache=cache,
+                kv_valid=jnp.full((batch,), P, jnp.int32),
+                use_kernels=uk).logits
 
+    return prefill, step
+
+
+def kernel_parity(cfg, params, prompt_len: int = PROMPT_LEN,
+                  gen_len: int = MAX_TOKENS, batch: int = 2,
+                  seed: int = 0) -> dict:
+    """One prefill and one denoise-step forward with the Pallas kernels
+    and with the jnp reference on the same inputs, all under float32
+    matmuls; returns the relative L2 error of the kernel logits, and of
+    the kernel step with its q, k and v rounded to bf16 (the control)."""
+    import jax
+    import jax.numpy as jnp
+
+    prefill, step = _forwards(cfg, prompt_len, gen_len)
+    rng = np.random.default_rng(seed)
+    toks = jnp.asarray(rng.integers(0, 256, (batch, prompt_len)),
+                       jnp.int32)
     with jax.default_matmul_precision("highest"):
         ref, cache = prefill(params, toks, False)
         ker, _ = prefill(params, toks, True)
         err_prefill = _rel_err(ker, ref)
         del ref, ker
-        ref = step(params, cache, False)
-        err_step = _rel_err(step(params, cache, True), ref)
-    err_default = _rel_err(step(params, cache, True), ref)
+        ref = step(params, cache, batch, False)
+        err_step = _rel_err(step(params, cache, batch, True), ref)
+        err_bf16 = _rel_err(step(params, cache, batch, True, True), ref)
     return {"prefill": err_prefill, "step": err_step,
-            "step_default_precision": err_default}
+            "step_bf16_attention": err_bf16}
+
+
+def gang_witness(cfg, params, prompt_len: int = PROMPT_LEN,
+                 gen_len: int = MAX_TOKENS, gang: int = FLEET_GANG,
+                 seed: int = 0) -> dict:
+    """One row's first-step logits (kernels on) alone and as row 0 of
+    two gangs of ``gang`` rows with different mates, one padded as the
+    scheduler pads (row 0 repeated). Per matmul precision ("highest":
+    float32; "default": what serving runs, bf16 passes on TPU):
+    ``cross_row``, the largest |difference| of row 0 between the two
+    gangs, is nonzero only if rows leak into each other; ``gang_size``
+    is the relative L2 difference between the row alone and in a gang,
+    and ``argmax_agree`` the share of its positions whose argmax is the
+    same in both."""
+    import jax
+    import jax.numpy as jnp
+
+    prefill, step = _forwards(cfg, prompt_len, gen_len)
+    rows = np.random.default_rng(seed).integers(
+        0, 256, (2 * gang - 2, prompt_len))
+    gangs = {"alone": rows[:1],
+             "padded": np.concatenate([rows[:gang - 1], rows[:1]]),
+             "other": np.concatenate([rows[:1], rows[gang - 1:]])}
+    out = {}
+    for prec in ("highest", "default"):
+        scope = jax.default_matmul_precision(prec) if prec == "highest" \
+            else contextlib.nullcontext()
+        row0 = {}
+        with scope:
+            for name, toks in gangs.items():
+                _, cache = prefill(params, jnp.asarray(toks, jnp.int32),
+                                   True)
+                row0[name] = np.asarray(step(params, cache, len(toks),
+                                             True)[0])
+                del cache
+        alone, padded = row0["alone"], row0["padded"]
+        out[prec] = {
+            "cross_row": float(np.abs(padded - row0["other"]).max()),
+            "gang_size": _rel_err(alone, padded),
+            "argmax_agree": float(np.mean(alone.argmax(-1)
+                                          == padded.argmax(-1)))}
+    return out
 
 
 def _metric(text: str, name: str) -> float:
@@ -238,11 +325,8 @@ def serve_and_check(engines, prompts, max_tokens: int,
             "compile_seconds": sum(s["compile_seconds"] for s in snaps)}
 
 
-def compiled_kernels(engine, prompt_len: int, max_tokens: int,
-                     batch: int) -> set:
-    """Names of the Pallas kernels the engine's compiled block program
-    calls as ``tpu_custom_call``."""
-    from repro.kernels.ops import compiled_kernels as kernel_calls
+def compile_block(engine, prompt_len: int, max_tokens: int, batch: int):
+    """The engine's compiled fused block program for its first block."""
     from repro.serving.types import round_up_blocks
     gen = round_up_blocks(max_tokens, engine.dcfg.block_size)
     dec = engine.scheduler.decoder_for(gen)
@@ -250,10 +334,9 @@ def compiled_kernels(engine, prompt_len: int, max_tokens: int,
     state = dec.prefill(np.ones((batch, prompt_len), np.int32),
                         cache=engine.pool.acquire(batch, T))
     try:
-        text = dec.lower_block(state).compile().as_text()
+        return dec.lower_block(state).compile()
     finally:
         engine.pool.release(batch, T, state.cache)
-    return kernel_calls(text)
 
 
 def _gib(n: float) -> str:
@@ -279,10 +362,24 @@ def run_one_chip(cfg, seed: int) -> None:
     err = kernel_parity(cfg, ex.params, seed=seed)
     print(f"kernel vs reference logits (rel L2, f32 matmuls): "
           f"prefill {err['prefill']:.3e}, step {err['step']:.3e}; "
-          f"bound {LOGIT_RTOL:.0e}; kernel step at default precision "
-          f"{err['step_default_precision']:.3e}")
+          f"bound {LOGIT_RTOL:.0e}; control with bf16 attention inputs "
+          f"{err['step_bf16_attention']:.3e}")
     check(err["prefill"] <= LOGIT_RTOL and err["step"] <= LOGIT_RTOL,
           f"kernel logits off the reference: {err}")
+    check(err["step_bf16_attention"] > LOGIT_RTOL,
+          f"bf16 attention passes the bound: {err}")
+
+    wit = gang_witness(cfg, ex.params, seed=seed)
+    for prec, w in wit.items():
+        print(f"gang witness ({prec} matmuls): row 0 across gang mates "
+              f"max |diff| {w['cross_row']:.3e}; alone vs in a gang of "
+              f"{FLEET_GANG}: rel L2 {w['gang_size']:.3e}, argmax agrees "
+              f"at {w['argmax_agree']:.3f} of positions")
+    check(all(w["cross_row"] == 0.0 for w in wit.values()),
+          f"a row's logits depend on its gang mates: {wit}")
+    check(wit["highest"]["gang_size"] <= LOGIT_RTOL,
+          f"a row's logits change with the gang size beyond rounding: "
+          f"{wit}")
 
     eng = make_engine(cfg, ex, MAX_TOKENS, MAX_SLOTS)
     (rep,) = prewarm([eng], PROMPT_LEN, MAX_TOKENS)
@@ -297,8 +394,15 @@ def run_one_chip(cfg, seed: int) -> None:
           f"tok/s; host_syncs_per_block {out['host_syncs_per_block']}, "
           f"post-warm compiles {out['post_warm_compiles']:g}")
 
-    found = compiled_kernels(eng, PROMPT_LEN, MAX_TOKENS, batch=MAX_SLOTS)
-    print(f"tpu_custom_call kernels in the block program: {sorted(found)}")
+    from repro.kernels.ops import compiled_kernels
+    prog = compile_block(eng, PROMPT_LEN, MAX_TOKENS, batch=MAX_SLOTS)
+    found = compiled_kernels(prog.as_text())
+    mem = prog.memory_analysis()
+    print(f"tpu_custom_call kernels in the block program: {sorted(found)}; "
+          f"its memory analysis: arguments "
+          f"{_gib(mem.argument_size_in_bytes)}, temp "
+          f"{_gib(mem.temp_size_in_bytes)}, aliased "
+          f"{_gib(mem.alias_size_in_bytes)}")
     check(set(KERNELS) <= found, f"block program calls only {found}")
 
     from repro.obs.compile import persistent_cache_counters
@@ -329,10 +433,12 @@ def run_four_chips(cfg, seed: int) -> None:
               f"{_gib(pbytes)} of params")
         check(pbytes <= used < 1.1 * pbytes,
               f"{d} holds {_gib(used)}, not one copy of the params")
-    engines = [make_engine(cfg, ex, FLEET_MAX_TOKENS, FLEET_MAX_SLOTS)
+    engines = [make_engine(cfg, ex, FLEET_MAX_TOKENS, FLEET_GANG,
+                           batch_multiple=FLEET_GANG)
                for ex in executors]
     reps = prewarm(engines, PROMPT_LEN, FLEET_MAX_TOKENS)
-    print(f"prewarm: {[r['variants'] for r in reps]} variants, "
+    print(f"prewarm: {[r['variants'] for r in reps]} variants, batch "
+          f"sizes {reps[0]['batch_sizes']}, "
           f"{max(r['seconds'] for r in reps):.1f}s")
 
     prompts = make_prompts(N_REQUESTS, PROMPT_LEN, seed)

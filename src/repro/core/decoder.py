@@ -474,12 +474,25 @@ class DiffusionDecoder:
 
     @property
     def batch_invariant(self) -> bool:
-        """True when per-row outputs are bit-identical regardless of how
-        rows are batched — the property the serving scheduler relies on
-        to compact/backfill batches without changing generations. Holds
-        for every method except dkv, whose step-level KV freezing
-        accumulates ulp-level drift across appends under batch
-        reshaping (empirically verified in tests/test_serving.py)."""
+        """True when a row's result depends on no other row — the
+        property the serving scheduler relies on to compact, pad, merge
+        and steal rows without changing generations. Holds for every
+        method except dkv, whose step-level KV freezing accumulates
+        ulp-level drift across appends under batch reshaping
+        (tests/test_serving.py).
+
+        What it does not promise: a different gang *size* rounds the
+        matmuls differently (``chip_smoke.gang_witness``: ~1e-6 relative
+        on the logits in float32, on XLA:CPU and TPU; ~4e-3 on the TPU
+        at the default precision, whose bf16 passes round differently
+        per shape). Tokens stay equal unless an argmax or a threshold
+        sits within that rounding of a tie: true of the CPU suite's
+        models, not of random LLaDA-8B weights on the TPU, where 1.6% of
+        argmaxes flip and gangs of 4 and of 2 gave different tokens for
+        3 of 8 requests. There compaction, pow2 padding, merges,
+        stealing and the shadow auditor's bit-for-bit compare are exact
+        only up to such flips. At a fixed gang size a row's logits are
+        bit-identical whatever its gang mates."""
         return self.dcfg.method != "dkv"
 
     @property

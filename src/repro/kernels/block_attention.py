@@ -26,8 +26,8 @@ long_500k dense variant), and arbitrary KV validity (growing caches and
 the dKV position-indexed cache).
 
 The kernel runs compiled on TPU and in interpret mode on every other
-backend (``interpret=None`` picks from ``jax.default_backend()`` at
-trace time); the CPU tests validate it against
+backend (``interpret=None`` picks at trace time, see
+``kernels.mode``); the CPU tests validate it against
 ``ref.block_attention_ref``.
 """
 from __future__ import annotations
@@ -40,26 +40,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mode
+
 NEG_INF = -1e30
 # Clamp for the running max so fully-masked tiles don't produce
 # exp(-inf - (-inf)) = 1 artifacts.
 M_CLAMP = -1e4
-
-
-def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Interpret mode unless the kernel is being traced for a TPU."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
-def matmul_precision():
-    """In-kernel dot precision following the caller's
-    ``jax.default_matmul_precision`` scope, so a float32 reference run
-    under "highest" also gets full-precision MXU passes in the kernel."""
-    if jax.config.jax_default_matmul_precision in ("highest", "float32"):
-        return jax.lax.Precision.HIGHEST
-    return None
 
 
 def _kernel(q_ref, k_ref, v_ref, qpos_ref, kvpos_ref, kvmask_ref,
@@ -136,7 +122,7 @@ def block_attention(q, k, v, q_pos, kv_pos, kv_mask, *, scale,
     grid = (B, H, nq, nk)
     kernel = functools.partial(_kernel, scale=scale, softcap=softcap,
                                window=window, n_kv_tiles=nk,
-                               precision=matmul_precision())
+                               precision=mode.matmul_precision())
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -155,7 +141,7 @@ def block_attention(q, k, v, q_pos, kv_pos, kv_mask, *, scale,
             pltpu.VMEM((tq, 1), jnp.float32),
             pltpu.VMEM((tq, 1), jnp.float32),
         ],
-        interpret=resolve_interpret(interpret),
+        interpret=mode.resolve_interpret(interpret),
         name="block_attention",
     )(q.reshape(B, Sq_p, H * D), k.reshape(B, Skv_p, Hkv * D),
       v.reshape(B, Skv_p, Hkv * D),

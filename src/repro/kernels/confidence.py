@@ -11,7 +11,7 @@ vocab tiles through VMEM once, tracking running (max, sum-exp, argmax):
   logits tile (TS, TV) VMEM; scratch m/s (TS,1) f32, amax (TS,1) i32
 
 Runs compiled on TPU and in interpret mode elsewhere (see
-``block_attention.resolve_interpret``); validated on CPU against
+``kernels.mode``); validated on CPU against
 ref.confidence_argmax_ref.
 """
 from __future__ import annotations
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.block_attention import resolve_interpret
+from repro.kernels import mode
 
 NEG_INF = -1e30
 
@@ -83,7 +83,7 @@ def confidence_argmax(logits, *, ts: int = 128, tv: int = 512,
         scratch_shapes=[pltpu.VMEM((ts, 1), jnp.float32),
                         pltpu.VMEM((ts, 1), jnp.float32),
                         pltpu.VMEM((ts, 1), jnp.int32)],
-        interpret=resolve_interpret(interpret),
+        interpret=mode.resolve_interpret(interpret),
         name="confidence_argmax",
     )(x)
     return conf[:N, 0], idx[:N, 0]

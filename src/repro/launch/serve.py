@@ -337,8 +337,16 @@ def main():
     # --mesh. The params go through host memory first, so each device
     # holds exactly one copy (the default device none extra).
     executors = [None] * args.engines
-    if mesh_dims is None and 1 < args.engines <= len(jax.devices()):
-        mesh_dims = (1, 1)
+    if mesh_dims is None and args.engines > 1:
+        if args.engines <= len(jax.devices()):
+            mesh_dims = (1, 1)
+        elif jax.default_backend() != "cpu":
+            raise SystemExit(
+                f"--engines {args.engines} needs one device each, have "
+                f"{len(jax.devices())} (pass --mesh to place them)")
+        else:
+            print(f"all {args.engines} engines run on the default CPU "
+                  "device (the host budget splits its cores)")
     if mesh_dims is not None:
         from repro.launch.mesh import make_submeshes
         from repro.serving import DecodeExecutor

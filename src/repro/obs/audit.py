@@ -17,7 +17,10 @@ three always-on pieces:
   (dkv is documented as not batch-invariant, so its divergences are
   expected structure, not alarms). A B=1 re-decode is a valid oracle
   for every *other* method precisely because they are batch-invariant
-  (the PR 1 contract the compaction and steal tests already rely on).
+  (the PR 1 contract the compaction and steal tests already rely on) —
+  up to the gang size's rounding, which can flip a near-tie argmax
+  (``DiffusionDecoder.batch_invariant``): on the TPU with random
+  weights a divergence may be that, not a fault.
 
 * **Confidence calibration + early-exit regret.** The fused loop's
   carry now returns each committed token's commit-time confidence

@@ -39,7 +39,8 @@ class ContinuousEngine:
                  max_waiting: Optional[int] = None,
                  tokenizer=None, mesh=None, pad_pow2: bool = False,
                  executor=None, prefix_cache=None, tracer=None,
-                 host_budget=None, prefill_only: bool = False):
+                 host_budget=None, prefill_only: bool = False,
+                 batch_multiple: Optional[int] = None):
         self.cfg = cfg
         self.dcfg = dcfg
         self.executor = executor
@@ -67,6 +68,7 @@ class ContinuousEngine:
             cfg, params, dcfg, max_slots=max_slots, max_gang=max_gang,
             pool=self.pool, max_waiting=max_waiting, tokenizer=self.tok,
             mesh=mesh, pad_pow2=pad_pow2, executor=executor,
+            batch_multiple=batch_multiple,
             prefix_cache=prefix_cache, prefill_only=prefill_only,
             tracer=tracer, telemetry=self.telemetry,
             block_hist=self.metrics.hist_block_wall)

@@ -13,11 +13,13 @@ queue at the same tick, and the old KV buffer returns to the
 causes a recompile.
 
 Exactness: compaction relies on ``DiffusionDecoder.batch_invariant`` —
-per-row results are bit-identical under batch reshaping for every
-method except dkv, whose step-level KV freezing drifts at ulp level
-when the batch changes. dkv gangs therefore keep their admitted batch
-until every row finishes (matching the synchronous engine), while the
-other methods shrink and backfill freely.
+no row's result depends on another row, for every method except dkv,
+whose step-level KV freezing drifts at ulp level when the batch
+changes. dkv gangs therefore keep their admitted batch until every row
+finishes (matching the synchronous engine), while the other methods
+shrink and backfill freely. A new gang size still rounds the matmuls
+differently, so a near-tie argmax can flip (seen on the TPU with random
+weights; see ``batch_invariant``).
 
 Preemption is block-level: ``preempt(uid)`` extracts the row's
 ``DecodeState`` at the next block boundary, parks it without a KV

@@ -33,6 +33,19 @@ def engines(tiny):
     return engs
 
 
+@pytest.fixture(scope="module")
+def fleet(tiny):
+    """Engines as the --chips 4 phase builds them: every gang padded to
+    ``FLEET_GANG`` rows."""
+    cfg, ex = tiny
+    engs = [smoke.make_engine(cfg, ex, G, smoke.FLEET_GANG,
+                              batch_multiple=smoke.FLEET_GANG)
+            for _ in range(2)]
+    reps = smoke.prewarm(engs, P, G)
+    assert all(r["batch_sizes"] == [1, smoke.FLEET_GANG] for r in reps)
+    return engs
+
+
 def test_serve_and_check_one_engine(engines):
     prompts = smoke.make_prompts(6, P, seed=1)
     assert len({len(p) for p in prompts}) == 1
@@ -44,13 +57,14 @@ def test_serve_and_check_one_engine(engines):
     assert all(len(t) <= G for t in out["tokens"].values())
 
 
-def test_two_engines_match_one(engines):
-    """The --chips 4 comparison at CPU scale: the streaming method is
-    batch-invariant, so a router over two engines returns exactly the
-    tokens one engine does, and both engines serve."""
+def test_two_engines_match_one(fleet):
+    """The --chips 4 comparison at CPU scale: with every gang the same
+    size, a row's tokens do not depend on its gang mates, so a router
+    over two engines returns exactly the tokens one engine does, and
+    both engines serve."""
     prompts = smoke.make_prompts(6, P, seed=2)
-    one = smoke.serve_and_check(engines[:1], prompts, G)
-    two = smoke.serve_and_check(engines, prompts, G)
+    one = smoke.serve_and_check(fleet[:1], prompts, G)
+    two = smoke.serve_and_check(fleet, prompts, G)
     assert sum(two["served"]) == 6 and min(two["served"]) >= 1
     assert one["tokens"] == two["tokens"]
 
@@ -67,14 +81,27 @@ def test_kernel_parity_tiny(tiny):
     err = smoke.kernel_parity(cfg, ex.params, prompt_len=P, gen_len=G)
     assert err["prefill"] <= smoke.LOGIT_RTOL
     assert err["step"] <= smoke.LOGIT_RTOL
+    assert err["step_bf16_attention"] > smoke.LOGIT_RTOL
+
+
+def test_gang_witness_tiny(tiny):
+    """No row leaks into another: row 0 is bit-identical across gang
+    mates. The gang's size moves a row's logits at the rounding level
+    only (XLA:CPU too), within the bound the chip run checks."""
+    cfg, ex = tiny
+    wit = smoke.gang_witness(cfg, ex.params, prompt_len=P, gen_len=G)
+    for w in wit.values():
+        assert w["cross_row"] == 0.0
+        assert w["gang_size"] <= smoke.LOGIT_RTOL
 
 
 def test_interpreted_kernels_are_not_custom_calls(tiny, engines):
     """On CPU the kernels run interpreted: they lower to plain HLO, so
     the compiled block program holds no tpu_custom_call — the check
     the chip run relies on tells the two apart."""
-    found = smoke.compiled_kernels(engines[0], P, G, batch=2)
-    assert not set(smoke.KERNELS) & found
+    from repro.kernels.ops import compiled_kernels
+    prog = smoke.compile_block(engines[0], P, G, batch=2)
+    assert not set(smoke.KERNELS) & compiled_kernels(prog.as_text())
 
 
 def test_main_refuses_without_tpu(capsys):

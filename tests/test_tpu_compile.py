@@ -95,11 +95,9 @@ def no_interpret(monkeypatch):
     """The served path asks the backend whether to interpret its
     kernels, and the backend here is the CPU: steer that choice for one
     test, and drop every trace cached under it on both sides."""
-    import repro.kernels.block_attention as ba
-    import repro.kernels.confidence as cf
+    from repro.kernels import mode
     jax.clear_caches()
-    monkeypatch.setattr(ba, "resolve_interpret", lambda i: False)
-    monkeypatch.setattr(cf, "resolve_interpret", lambda i: False)
+    monkeypatch.setattr(mode, "resolve_interpret", lambda i: False)
     yield
     jax.clear_caches()
 
