@@ -53,6 +53,7 @@ from repro.kernels import ops as kops
 from repro.models.config import ModelConfig
 from repro.models.model import apply_model, cache_take_rows, init_cache
 from repro.obs.telemetry import CONF_BUCKETS, BlockStats
+from repro.obs.trace import span
 
 METHODS = ("vanilla", "dkv", "prefix", "fast", "streaming")
 
@@ -261,36 +262,39 @@ class DiffusionDecoder:
         (conf (B, K), toks (B, K)) without a monolithic (B, K, V)
         logits array. Kernel route when use_kernels."""
         cfg = self.cfg
-        if self.dcfg.use_kernels:
-            return kops.head_confidence_argmax(
+        with jax.named_scope("head_confidence"):
+            if self.dcfg.use_kernels:
+                return kops.head_confidence_argmax(
+                    h_blk, self._head(p), mask_id=cfg.mask_token_id,
+                    logit_softcap=cfg.logit_softcap)
+            return sched.head_confidence_and_tokens(
                 h_blk, self._head(p), mask_id=cfg.mask_token_id,
                 logit_softcap=cfg.logit_softcap)
-        return sched.head_confidence_and_tokens(
-            h_blk, self._head(p), mask_id=cfg.mask_token_id,
-            logit_softcap=cfg.logit_softcap)
 
     def _conf_from_logits(self, blk_logits):
         """Full-vocab path (fixed-schedule methods): ban [MASK], Eq. 4."""
-        blk = blk_logits.astype(jnp.float32)
-        blk = blk.at[..., self.cfg.mask_token_id].set(-1e30)
-        return sched.confidence_and_tokens(blk)
+        with jax.named_scope("head_confidence"):
+            blk = blk_logits.astype(jnp.float32)
+            blk = blk.at[..., self.cfg.mask_token_id].set(-1e30)
+            return sched.confidence_and_tokens(blk)
 
     # ------------------------------------------------------ jitted steps
 
     def _encode_fn(self):
         if "encode" not in self._fns:
             uk = self.dcfg.use_kernels
-            self._fns["encode"] = jax.jit(
-                lambda p, toks, pos: apply_model(
-                    self.cfg, p, tokens=toks, positions=pos,
-                    use_kernels=uk).logits)
+
+            def encode(p, toks, pos):
+                return apply_model(self.cfg, p, tokens=toks, positions=pos,
+                                   use_kernels=uk).logits
+            self._fns["encode"] = jax.jit(encode)
         return self._fns["encode"]
 
     def _prefill_fn(self):
         if "prefill" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache):
+            def prefill(p, toks, pos, cache):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="encode", cache=cache, use_kernels=uk)
                 c = out.cache
@@ -300,7 +304,7 @@ class DiffusionDecoder:
                     c = self.executor.constrain_cache(
                         c, toks.shape[0], toks.shape[1])
                 return c, out.kv_valid
-            self._fns["prefill"] = jax.jit(f)
+            self._fns["prefill"] = jax.jit(prefill)
         return self._fns["prefill"]
 
     def _refresh_fn(self):
@@ -313,12 +317,13 @@ class DiffusionDecoder:
         if "refresh" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache, *, upto):
+            def refresh(p, toks, pos, cache, *, upto):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="encode", cache=cache,
                                   cache_upto=upto, use_kernels=uk)
                 return out.logits, out.cache
-            self._fns["refresh"] = jax.jit(f, static_argnames=("upto",))
+            self._fns["refresh"] = jax.jit(
+                refresh, static_argnames=("upto",))
         return self._fns["refresh"]
 
     def _refresh_ct_fn(self):
@@ -327,40 +332,41 @@ class DiffusionDecoder:
         if "refresh_ct" not in self._fns:
             uk, K = self.dcfg.use_kernels, self.dcfg.block_size
 
-            def f(p, toks, pos, cache, *, upto):
+            def refresh_ct(p, toks, pos, cache, *, upto):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="encode", cache=cache,
                                   cache_upto=upto, skip_head=True,
                                   use_kernels=uk)
                 c, t = self._conf_from_hidden(p, out.logits[:, upto:upto + K])
                 return c, t, out.cache
-            self._fns["refresh_ct"] = jax.jit(f, static_argnames=("upto",))
+            self._fns["refresh_ct"] = jax.jit(
+                refresh_ct, static_argnames=("upto",))
         return self._fns["refresh_ct"]
 
     def _step_fn(self):
         if "step" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache, kv_valid):
+            def step(p, toks, pos, cache, kv_valid):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="step", cache=cache, kv_valid=kv_valid,
                                   mesh=self.mesh, data_axes=self.data_axes,
                                   use_kernels=uk)
                 return out.logits
-            self._fns["step"] = jax.jit(f)
+            self._fns["step"] = jax.jit(step)
         return self._fns["step"]
 
     def _step_ct_fn(self):
         if "step_ct" not in self._fns:
             uk, K = self.dcfg.use_kernels, self.dcfg.block_size
 
-            def f(p, toks, pos, cache, kv_valid):
+            def step_ct(p, toks, pos, cache, kv_valid):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="step", cache=cache, kv_valid=kv_valid,
                                   mesh=self.mesh, data_axes=self.data_axes,
                                   skip_head=True, use_kernels=uk)
                 return self._conf_from_hidden(p, out.logits[:, :K])
-            self._fns["step_ct"] = jax.jit(f)
+            self._fns["step_ct"] = jax.jit(step_ct)
         return self._fns["step_ct"]
 
     def _chunk_prefill_fn(self):
@@ -373,7 +379,7 @@ class DiffusionDecoder:
         if "chunk_prefill" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache, kv_valid):
+            def chunk_prefill(p, toks, pos, cache, kv_valid):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="append", cache=cache,
                                   kv_valid=kv_valid, skip_head=True,
@@ -385,7 +391,7 @@ class DiffusionDecoder:
                     c = self.executor.constrain_cache(
                         c, toks.shape[0], toks.shape[1])
                 return c
-            self._fns["chunk_prefill"] = jax.jit(f)
+            self._fns["chunk_prefill"] = jax.jit(chunk_prefill)
         return self._fns["chunk_prefill"]
 
     def _tail_refresh_fn(self):
@@ -397,12 +403,12 @@ class DiffusionDecoder:
         if "tail_refresh" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache, kv0):
+            def tail_refresh(p, toks, pos, cache, kv0):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="append", cache=cache, kv_valid=kv0,
                                   use_kernels=uk)
                 return out.logits, out.cache
-            self._fns["tail_refresh"] = jax.jit(f)
+            self._fns["tail_refresh"] = jax.jit(tail_refresh)
         return self._fns["tail_refresh"]
 
     def _tail_refresh_ct_fn(self):
@@ -412,26 +418,26 @@ class DiffusionDecoder:
         if "tail_refresh_ct" not in self._fns:
             uk, K = self.dcfg.use_kernels, self.dcfg.block_size
 
-            def f(p, toks, pos, cache, kv0, *, upto):
+            def tail_refresh_ct(p, toks, pos, cache, kv0, *, upto):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="append", cache=cache, kv_valid=kv0,
                                   skip_head=True, use_kernels=uk)
                 c, t = self._conf_from_hidden(p, out.logits[:, upto:upto + K])
                 return c, t, out.cache
             self._fns["tail_refresh_ct"] = jax.jit(
-                f, static_argnames=("upto",))
+                tail_refresh_ct, static_argnames=("upto",))
         return self._fns["tail_refresh_ct"]
 
     def _append_fn(self):
         if "append" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache, kv_valid):
+            def append(p, toks, pos, cache, kv_valid):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="append", cache=cache,
                                   kv_valid=kv_valid, use_kernels=uk)
                 return out.cache, out.kv_valid
-            self._fns["append"] = jax.jit(f)
+            self._fns["append"] = jax.jit(append)
         return self._fns["append"]
 
     def _frozen_refresh_ct_fn(self):
@@ -443,7 +449,7 @@ class DiffusionDecoder:
         if "frozen_refresh_ct" not in self._fns:
             uk, K = self.dcfg.use_kernels, self.dcfg.block_size
 
-            def f(p, toks, pos, cache, *, upto):
+            def frozen_refresh_ct(p, toks, pos, cache, *, upto):
                 B = toks.shape[0]
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="append", cache=cache,
@@ -454,20 +460,20 @@ class DiffusionDecoder:
                 c, t = self._conf_from_hidden(p, out.logits[:, upto:upto + K])
                 return c, t, out.cache
             self._fns["frozen_refresh_ct"] = jax.jit(
-                f, static_argnames=("upto",))
+                frozen_refresh_ct, static_argnames=("upto",))
         return self._fns["frozen_refresh_ct"]
 
     def _dkv_step_fn(self):
         if "dkv" not in self._fns:
             uk = self.dcfg.use_kernels
 
-            def f(p, toks, pos, cache, valid_mask, mix):
+            def dkv(p, toks, pos, cache, valid_mask, mix):
                 out = apply_model(self.cfg, p, tokens=toks, positions=pos,
                                   mode="append", cache=cache,
                                   kv_valid=valid_mask, append_at=pos,
                                   self_kv_mix=mix, use_kernels=uk)
                 return out.logits, out.cache
-            self._fns["dkv"] = jax.jit(f)
+            self._fns["dkv"] = jax.jit(dkv)
         return self._fns["dkv"]
 
     # ------------------------------------------------------ resumable API
@@ -791,9 +797,13 @@ class DiffusionDecoder:
         method has one) + a ``lax.while_loop`` over denoise steps +
         straggler finalize + EOS early exit, compiled as ONE function.
         Specialized per (method, shapes, bstart); the host calls it once
-        per block and syncs once on its outputs."""
-        if "fused" in self._fns:
-            return self._fns["fused"]
+        per block and syncs once on its outputs. Its phases carry named
+        scopes, so a device trace can tell them apart: ``refresh`` (the
+        block-start pass with its confidence and commit),
+        ``denoise_step`` (the ``while_loop`` body), ``head_confidence``
+        (inside both) and ``finalize`` (straggler fill, early exit)."""
+        if "decode_block" in self._fns:
+            return self._fns["decode_block"]
         cfg, d = self.cfg, self.dcfg
         eos_id = cfg.eos_token_id   # the [MASK] ban lives in _conf_from_*
         K = d.block_size
@@ -825,8 +835,8 @@ class DiffusionDecoder:
                 blk_committed | commit)
             return x, committed, commit
 
-        def f(p, x, committed, done, cache, qpos_b, valid_mask, cached_mask,
-              *, bstart, pstart):
+        def decode_block(p, x, committed, done, cache, qpos_b, valid_mask,
+                         cached_mask, *, bstart, pstart):
             B, T = x.shape
             prefix_len = bstart
             vsums = jnp.zeros((steps_cap,), jnp.int32)  # dkv kv-size trace
@@ -868,6 +878,7 @@ class DiffusionDecoder:
                     committed, step = c[1], c[2]
                     return loop_open(committed, step)
 
+                @jax.named_scope("denoise_step")
                 def body(c):
                     x, committed, step, _, counts, hist, cconf, _ = c
                     out = apply_model(cfg, p, tokens=x, positions=pos_T,
@@ -892,6 +903,7 @@ class DiffusionDecoder:
                     _, committed, step = c[0], c[1], c[2]
                     return loop_open(committed, step)
 
+                @jax.named_scope("denoise_step")
                 def body(c):
                     x, committed, step, _, cache, valid_mask, cached_mask, \
                         vsums, counts, hist, cconf, _ = c
@@ -933,50 +945,51 @@ class DiffusionDecoder:
                 # prefix_cache the pass starts at the prompt boundary
                 # (pstart): the prompt KV was computed at prefill and is
                 # attended via kv_valid, never recomputed.
-                pref_pos = jnp.broadcast_to(
-                    jnp.arange(pstart if d.prefix_cache else 0, prefix_len,
-                               dtype=jnp.int32)[None],
-                    (B, prefix_len - (pstart if d.prefix_cache else 0)))
-                full_pos = jnp.concatenate([pref_pos, qpos_b], axis=1)
-                full_toks = jnp.take_along_axis(x, full_pos, axis=1)
-                if d.prefix_cache:
-                    out = apply_model(cfg, p, tokens=full_toks,
-                                      positions=full_pos, mode="append",
-                                      cache=cache,
-                                      kv_valid=jnp.full((B,), pstart,
-                                                        jnp.int32),
-                                      skip_head=parallel, use_kernels=uk)
-                    valid = jnp.full((B,), prefix_len, jnp.int32)
-                elif frozen:
-                    out = apply_model(cfg, p, tokens=full_toks,
-                                      positions=full_pos, mode="append",
-                                      cache=cache,
-                                      kv_valid=jnp.zeros((B,), jnp.int32),
-                                      append_at=full_pos,
-                                      cache_upto=prefix_len, skip_head=True,
-                                      use_kernels=uk)
-                    valid = jnp.broadcast_to(
-                        jnp.arange(T) < prefix_len, (B, T))
-                    valid = valid.at[jnp.arange(B)[:, None],
-                                     qpos_b[:, K:]].set(True)
-                else:
-                    out = apply_model(cfg, p, tokens=full_toks,
-                                      positions=full_pos, mode="encode",
-                                      cache=cache, cache_upto=prefix_len,
-                                      skip_head=parallel, use_kernels=uk)
-                    valid = jnp.full((B,), prefix_len, jnp.int32)
-                cache = out.cache
-                boff = prefix_len - pstart if d.prefix_cache else prefix_len
-                blk_out = out.logits[:, boff:boff + K]
-                if parallel:
-                    conf, toks = self._conf_from_hidden(p, blk_out)
-                else:
-                    conf, toks = self._conf_from_logits(blk_out)
-                x, committed, commit = commit_tokens(x, committed, conf,
-                                                     toks, bstart)
-                counts, hist = tally(counts, hist, 0, commit, conf)
-                cconf = jnp.where(commit, conf, cconf)
-                lconf = conf
+                with jax.named_scope("refresh"):
+                    p0 = pstart if d.prefix_cache else 0
+                    pref_pos = jnp.broadcast_to(
+                        jnp.arange(p0, prefix_len, dtype=jnp.int32)[None],
+                        (B, prefix_len - p0))
+                    full_pos = jnp.concatenate([pref_pos, qpos_b], axis=1)
+                    full_toks = jnp.take_along_axis(x, full_pos, axis=1)
+                    if d.prefix_cache:
+                        out = apply_model(cfg, p, tokens=full_toks,
+                                          positions=full_pos, mode="append",
+                                          cache=cache,
+                                          kv_valid=jnp.full((B,), pstart,
+                                                            jnp.int32),
+                                          skip_head=parallel, use_kernels=uk)
+                        valid = jnp.full((B,), prefix_len, jnp.int32)
+                    elif frozen:
+                        out = apply_model(cfg, p, tokens=full_toks,
+                                          positions=full_pos, mode="append",
+                                          cache=cache,
+                                          kv_valid=jnp.zeros((B,), jnp.int32),
+                                          append_at=full_pos,
+                                          cache_upto=prefix_len,
+                                          skip_head=True, use_kernels=uk)
+                        valid = jnp.broadcast_to(
+                            jnp.arange(T) < prefix_len, (B, T))
+                        valid = valid.at[jnp.arange(B)[:, None],
+                                         qpos_b[:, K:]].set(True)
+                    else:
+                        out = apply_model(cfg, p, tokens=full_toks,
+                                          positions=full_pos, mode="encode",
+                                          cache=cache, cache_upto=prefix_len,
+                                          skip_head=parallel, use_kernels=uk)
+                        valid = jnp.full((B,), prefix_len, jnp.int32)
+                    cache = out.cache
+                    boff = prefix_len - p0
+                    blk_out = out.logits[:, boff:boff + K]
+                    if parallel:
+                        conf, toks = self._conf_from_hidden(p, blk_out)
+                    else:
+                        conf, toks = self._conf_from_logits(blk_out)
+                    x, committed, commit = commit_tokens(x, committed, conf,
+                                                         toks, bstart)
+                    counts, hist = tally(counts, hist, 0, commit, conf)
+                    cconf = jnp.where(commit, conf, cconf)
+                    lconf = conf
 
                 if frozen:
                     bpos = jnp.broadcast_to(
@@ -987,6 +1000,7 @@ class DiffusionDecoder:
                     committed, step = c[1], c[2]
                     return loop_open(committed, step)
 
+                @jax.named_scope("denoise_step")
                 def body(c):
                     x, committed, step, _, counts, hist, cconf, _ = c
                     if frozen:
@@ -1024,25 +1038,26 @@ class DiffusionDecoder:
                 x, committed, steps, toks, counts, hist, cconf, lconf = \
                     jax.lax.while_loop(cond, body, init)
 
-            # straggler finalize (steps cap reached): commit the last
-            # step's argmax — but never overwrite rows that early-exited
-            # in a prior block (their tail is EOS-truncated territory)
-            blk = x[:, bstart:bstart + K]
-            blk_masked = ~committed[:, bstart:bstart + K]
-            fill = blk_masked & ~done[:, None] & (steps > 0)
-            fill_n = jnp.sum(fill.astype(jnp.int32))
-            cconf = jnp.where(fill, lconf, cconf)
-            blk = jnp.where(fill, toks, blk)
-            x = x.at[:, bstart:bstart + K].set(blk)
-            committed = committed.at[:, bstart:bstart + K].set(True)
-            # Early exit (paper §3.3): a block that decoded an EOS makes
-            # all *subsequent* blocks skippable for that row.
-            if d.early_exit:
-                hit = jnp.any(blk == eos_id, axis=1) & ~done
-                n_hit = jnp.sum(hit.astype(jnp.int32))
-                done = done | hit
-            else:
-                n_hit = jnp.int32(0)
+            with jax.named_scope("finalize"):
+                # straggler finalize (steps cap reached): commit the last
+                # step's argmax — but never overwrite rows that early-exited
+                # in a prior block (their tail is EOS-truncated territory)
+                blk = x[:, bstart:bstart + K]
+                blk_masked = ~committed[:, bstart:bstart + K]
+                fill = blk_masked & ~done[:, None] & (steps > 0)
+                fill_n = jnp.sum(fill.astype(jnp.int32))
+                cconf = jnp.where(fill, lconf, cconf)
+                blk = jnp.where(fill, toks, blk)
+                x = x.at[:, bstart:bstart + K].set(blk)
+                committed = committed.at[:, bstart:bstart + K].set(True)
+                # Early exit (paper §3.3): a block that decoded an EOS makes
+                # all *subsequent* blocks skippable for that row.
+                if d.early_exit:
+                    hit = jnp.any(blk == eos_id, axis=1) & ~done
+                    n_hit = jnp.sum(hit.astype(jnp.int32))
+                    done = done | hit
+                else:
+                    n_hit = jnp.int32(0)
             if self.executor is not None:
                 # pin the output cache to the canonical placement so a
                 # recycled pool buffer is sharding-identical to a fresh
@@ -1062,9 +1077,10 @@ class DiffusionDecoder:
         donate = (4,) if (self.executor is not None
                           and self.executor.donate_cache
                           and d.method != "vanilla") else ()
-        self._fns["fused"] = jax.jit(f, static_argnames=("bstart", "pstart"),
-                                     donate_argnums=donate)
-        return self._fns["fused"]
+        self._fns["decode_block"] = jax.jit(
+            decode_block, static_argnames=("bstart", "pstart"),
+            donate_argnums=donate)
+        return self._fns["decode_block"]
 
     def _fused_inputs(self, state: DecodeState, region, qpos):
         """Device arguments and static kwargs of the fused fn for the
@@ -1105,62 +1121,69 @@ class DiffusionDecoder:
         prefix_len = region.block_start
 
         live_rows = int((~state.done).sum())
-        args, static = self._fused_inputs(state, region, qpos)
-        (x, committed, done, steps, n_hit, cache, vm, cm,
-         vsums, counts, hist, fill_n, cconf) = self._fused_fn()(
-            *args, **static)
+        # profiler-only spans (no tracer here): host->device puts, the
+        # dispatch of the block program, and from the first readback
+        # on, so a device trace can tell which host work its idle
+        # gaps wait on
+        with span(None, "decoder.inputs"):
+            args, static = self._fused_inputs(state, region, qpos)
+        with span(None, "decoder.dispatch"):
+            (x, committed, done, steps, n_hit, cache, vm, cm,
+             vsums, counts, hist, fill_n, cconf) = self._fused_fn()(
+                *args, **static)
+        with span(None, "decoder.sync"):
+            # the ONE host sync for this block (np.array: writable copies —
+            # the scheduler and finalize mutate these buffers in place).
+            # The telemetry outputs (counts/hist/fill_n) materialize with
+            # the rest of this call's results — no extra sync.
+            state.x = np.array(x)
+            state.committed = np.array(committed)
+            state.done = np.array(done)
+            steps = int(steps)
+            n_hit = int(n_hit)
+            counts = np.asarray(counts)
+            hist = np.asarray(hist)
+            state.early_exits += n_hit
+            state.host_syncs += 1
+            state.cache = cache
+            if vm is not None:
+                state.valid_mask = np.array(vm)
+                state.cached_mask = np.array(cm)
 
-        # the ONE host sync for this block (np.array: writable copies —
-        # the scheduler and finalize mutate these buffers in place).
-        # The telemetry outputs (counts/hist/fill_n) materialize with
-        # the rest of this call's results — no extra sync.
-        state.x = np.array(x)
-        state.committed = np.array(committed)
-        state.done = np.array(done)
-        steps = int(steps)
-        n_hit = int(n_hit)
-        counts = np.asarray(counts)
-        hist = np.asarray(hist)
-        state.early_exits += n_hit
-        state.host_syncs += 1
-        state.cache = cache
-        if vm is not None:
-            state.valid_mask = np.array(vm)
-            state.cached_mask = np.array(cm)
-
-        state.steps_per_block.append(steps)
-        state.nfe += steps
-        if d.method == "vanilla":
-            state.q_tokens += steps * B * T
-            state.kv_tokens += steps * B * T * T
-        elif d.method == "dkv":
-            state.q_tokens += steps * B * Sq
-            for vs in np.asarray(vsums)[:steps]:
-                state.kv_tokens += B * Sq * (int(vs) + Sq)
-        elif steps > 0:
-            # cached mode: the refresh pass covers only the generated
-            # prefix + query (the prompt is attended, not recomputed)
-            ref_q = (prefix_len - P if d.prefix_cache else prefix_len) + Sq
-            state.q_tokens += B * ref_q
-            state.kv_tokens += B * ref_q * (prefix_len + Sq)
-            if frozen:
-                state.q_tokens += (steps - 1) * B * K
-                state.kv_tokens += (steps - 1) * B * K * (prefix_len + Sq + K)
-            else:
-                state.q_tokens += (steps - 1) * B * Sq
-                state.kv_tokens += (steps - 1) * B * Sq * (prefix_len + Sq)
-        state.block_idx = region.block_idx + 1
-        wall = time.perf_counter() - t_block
-        state.block_stats.append(BlockStats(
-            method=d.method, block_idx=region.block_idx, batch=B,
-            live_rows=live_rows, steps=steps, steps_cap=steps_cap,
-            committed_per_step=[int(v) for v in counts[:steps]],
-            straggler_fill=int(fill_n),
-            conf_hist=[int(v) for v in hist],
-            window=Sq, early_exits=n_hit, wall_s=wall,
-            commit_conf=np.asarray(cconf, np.float32)))
-        state.decode_time += wall
-        return state
+            state.steps_per_block.append(steps)
+            state.nfe += steps
+            if d.method == "vanilla":
+                state.q_tokens += steps * B * T
+                state.kv_tokens += steps * B * T * T
+            elif d.method == "dkv":
+                state.q_tokens += steps * B * Sq
+                for vs in np.asarray(vsums)[:steps]:
+                    state.kv_tokens += B * Sq * (int(vs) + Sq)
+            elif steps > 0:
+                # cached mode: the refresh pass covers only the generated
+                # prefix + query (the prompt is attended, not recomputed)
+                ref_q = (prefix_len - P if d.prefix_cache else prefix_len) + Sq
+                state.q_tokens += B * ref_q
+                state.kv_tokens += B * ref_q * (prefix_len + Sq)
+                if frozen:
+                    state.q_tokens += (steps - 1) * B * K
+                    state.kv_tokens += ((steps - 1) * B * K
+                                        * (prefix_len + Sq + K))
+                else:
+                    state.q_tokens += (steps - 1) * B * Sq
+                    state.kv_tokens += (steps - 1) * B * Sq * (prefix_len + Sq)
+            state.block_idx = region.block_idx + 1
+            wall = time.perf_counter() - t_block
+            state.block_stats.append(BlockStats(
+                method=d.method, block_idx=region.block_idx, batch=B,
+                live_rows=live_rows, steps=steps, steps_cap=steps_cap,
+                committed_per_step=[int(v) for v in counts[:steps]],
+                straggler_fill=int(fill_n),
+                conf_hist=[int(v) for v in hist],
+                window=Sq, early_exits=n_hit, wall_s=wall,
+                commit_conf=np.asarray(cconf, np.float32)))
+            state.decode_time += wall
+            return state
 
     # --------------------------------------------------- legacy host loop
 

@@ -215,7 +215,10 @@ def main():
     ap.add_argument("--profile-blocks", type=int, default=0, metavar="N",
                     help="capture a jax.profiler trace over the first "
                          "N decoded blocks (written under --trace-dir, "
-                         "or results/profile)")
+                         "or results/profile), without Python function "
+                         "tracing: it shows the program's decoder.*, "
+                         "scheduler.*, engine.* and loop.wait spans, as "
+                         "the chip benchmark's capture does")
     ap.add_argument("--log-level", default="info",
                     choices=["debug", "info", "warning", "error"])
     ap.add_argument("--log-json", action="store_true",

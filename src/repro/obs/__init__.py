@@ -8,7 +8,9 @@ Layering (threaded through every serving layer):
                  clocks), exported as Chrome-trace JSON loadable in
                  Perfetto: one track per engine/decode thread plus an
                  async track per request (accept → admission → blocks
-                 → finalize), correlated by trace id.
+                 → finalize), correlated by trace id. ``span`` also
+                 writes every thread-track span into a running
+                 ``jax.profiler`` capture, on the device trace's clock.
     telemetry  — per-block diffusion dynamics harvested from the fused
                  decode loop in its ONE existing host sync (steps used
                  vs the τ-schedule cap, tokens committed per step,
@@ -36,7 +38,9 @@ Layering (threaded through every serving layer):
                  JSONL persistence.
 
 Everything is optional: a ``tracer=None`` (the default everywhere)
-costs one ``is None`` test per call site, and telemetry rides inside
+leaves a ``span`` one profiler annotation (about a microsecond with no
+capture running), other call sites one ``is None`` test, and telemetry
+rides inside
 the already-compiled fused loop, so ``host_syncs_per_block`` is
 unchanged with observability on.
 """

@@ -4,8 +4,12 @@
 steady-state region that matters (skipping jit warm-up is the caller's
 job — the engine ticks the profiler only after its warm-up wave).
 The capture is written as a TensorBoard-loadable trace under
-``<trace_dir>/jax_profile``; it complements the host-side Chrome trace
-the :class:`~repro.obs.trace.Tracer` exports.
+``<trace_dir>/jax_profile``. It holds the program's own spans
+(``repro.obs.trace.span``: ``decoder.*``, ``scheduler.*``,
+``engine.*``, ``loop.wait``) on the device's clock, without Python
+function tracing, as the chip benchmark's capture does; the same spans
+also feed the host-side Chrome trace the
+:class:`~repro.obs.trace.Tracer` exports.
 
 Failure to start the profiler (unsupported backend, second profiler
 already live) degrades to a no-op with a warning — observability must
@@ -44,7 +48,14 @@ class BlockProfiler:
             try:
                 import jax
                 os.makedirs(self.trace_dir, exist_ok=True)
-                jax.profiler.start_trace(self.trace_dir)
+                opts = jax.profiler.ProfileOptions()
+                # no Python function tracing: it would slow the host
+                # path between blocks that the capture is there to show;
+                # the program's spans (repro.obs.trace.span) are
+                # recorded without it
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(self.trace_dir,
+                                         profiler_options=opts)
                 self.active = True
                 log.info("jax profiler started",
                          extra={"trace_dir": self.trace_dir,

@@ -24,13 +24,16 @@ Design:
   fact (a decoded block, a queue wait) is emitted *once*, complete —
   no dangling ``b`` if the process stops mid-request.
 
-``span(tracer, name, ...)`` is the call-site helper: with
-``tracer=None`` (observability off) it returns a shared no-op context
-manager, so instrumented code pays one ``is None`` test.
+``span(tracer, name, ...)`` is the call-site helper, and it writes to
+two sinks from one call: it always enters a
+``jax.profiler.TraceAnnotation`` (about a microsecond when no profiler
+capture is running; a capture records the span with its args on the
+device trace's clock), and it also records to the tracer's ring when
+``tracer`` is not ``None``. ``annotate(**kw)`` on the returned span adds
+args known only at its end to both.
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import json
 import os
@@ -39,32 +42,45 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
-_NULL_CTX = contextlib.nullcontext()
+from jax.profiler import TraceAnnotation
 
 
-def span(tracer: Optional["Tracer"], name: str, **args):
-    """Thread-track span helper for maybe-absent tracers."""
-    return _NULL_CTX if tracer is None else tracer.span(name, **args)
+def span(tracer: Optional["Tracer"], name: str, pid: int = 0, **args):
+    """Thread-track span: always a profiler annotation (``pid`` stays
+    out of it), and a complete event in ``tracer``'s ring when one is
+    attached."""
+    return _Span(tracer, name, pid, args)
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
-    __slots__ = ("tr", "name", "pid", "args", "t0")
+    """Context manager around one profiler annotation that, with a
+    tracer, also records one complete ("X") event on exit."""
+    __slots__ = ("tr", "name", "pid", "args", "t0", "ann")
 
-    def __init__(self, tr: "Tracer", name: str, pid: int, args: dict):
+    def __init__(self, tr: Optional["Tracer"], name: str, pid: int,
+                 args: dict):
         self.tr = tr
         self.name = name
         self.pid = pid
         self.args = args
 
     def __enter__(self):
-        self.t0 = time.perf_counter_ns()
+        self.ann = TraceAnnotation(self.name, **self.args)
+        self.ann.__enter__()
+        if self.tr is not None:
+            self.t0 = time.perf_counter_ns()
         return self
 
+    def annotate(self, **kw) -> None:
+        """Add args known only at the span's end, to both sinks."""
+        self.ann.set_metadata(**kw)
+        self.args.update(kw)
+
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
-        self.tr.complete(self.name, self.t0, t1, pid=self.pid,
-                         **self.args)
+        if self.tr is not None:
+            self.tr.complete(self.name, self.t0, time.perf_counter_ns(),
+                             pid=self.pid, **self.args)
+        self.ann.__exit__(*exc)
         return False
 
 

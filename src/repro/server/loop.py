@@ -49,6 +49,7 @@ from typing import Callable, List, Optional, Tuple
 import numpy as np
 
 from repro.obs.log import get_logger
+from repro.obs.trace import span
 from repro.serving.types import Completion
 from repro.server.types import AdmissionRejected, ServerRequest
 
@@ -328,8 +329,13 @@ class EngineLoop:
 
     def _drain_commands(self, block: bool) -> None:
         try:
-            cmd = self._cmds.get(timeout=self.idle_poll_s) if block \
-                else self._cmds.get_nowait()
+            if block:
+                # idle: the device waits for work, not for host code
+                with span(self.tracer, "loop.wait",
+                          pid=self.engine.obs_pid):
+                    cmd = self._cmds.get(timeout=self.idle_poll_s)
+            else:
+                cmd = self._cmds.get_nowait()
         except queue.Empty:
             return
         while True:

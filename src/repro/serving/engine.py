@@ -180,7 +180,7 @@ class ContinuousEngine:
                     # both families are compiled before admission.
                     for _ in range(3):
                         before_b = sched.jit_cache_size()
-                        with span(self.tracer, "prewarm",
+                        with span(self.tracer, "engine.prewarm",
                                   pid=self.obs_pid, batch=B,
                                   prompt_len=P, gen_len=gen_len):
                             self._prewarm_one(decoder, P, gen_len, B)
@@ -346,9 +346,11 @@ class ContinuousEngine:
         # occupancy uses the row count whose decode this tick paid for
         # (sampled pre-harvest), not the post-compaction remainder
         self.metrics.sample_tick(self.scheduler.last_decoded_rows, dt)
-        self.router.publish(chunks)
-        for comp in completions:
-            self._record(comp)
+        with span(self.tracer, "engine.publish", pid=self.obs_pid,
+                  chunks=len(chunks)):
+            self.router.publish(chunks)
+            for comp in completions:
+                self._record(comp)
         if chunks or completions:
             self.stats["batches"] += 1
         self.stats["time_s"] += dt

@@ -51,7 +51,7 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import data_axes_of
 from repro.launch.sharding import SpecBuilder
 from repro.models.config import ModelConfig
-from repro.models.model import init_cache, init_params
+from repro.models import model as model_lib
 
 
 class DecodeExecutor:
@@ -88,7 +88,10 @@ class DecodeExecutor:
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  SpecBuilder(cfg, mesh, mode="serve").params(),
                                  is_leaf=lambda x: isinstance(x, P))
-        params = jax.jit(lambda key: init_params(cfg, key),
+
+        def init_params(key):
+            return model_lib.init_params(cfg, key)
+        params = jax.jit(init_params,
                          out_shardings=shardings)(jax.random.PRNGKey(seed))
         return cls(cfg, params, mesh, **kw)
 
@@ -143,8 +146,10 @@ class DecodeExecutor:
         fn = self._cache_fns.get(key)
         if fn is None:
             shardings = self._shardings(self._sb.cache(batch, total_len))
-            fn = jax.jit(lambda: init_cache(self.cfg, batch, total_len),
-                         out_shardings=shardings)
+
+            def init_cache():
+                return model_lib.init_cache(self.cfg, batch, total_len)
+            fn = jax.jit(init_cache, out_shardings=shardings)
             self._cache_fns[key] = fn
         return fn()
 

@@ -216,8 +216,10 @@ class BlockScheduler:
         self.merges = 0            # cross-gang straggler merges performed
         # observability (repro.obs) — all optional. ``tracer`` records
         # queue/decode/block spans on the request's async track plus
-        # prefill/decode_block spans on this engine's thread track
-        # (``pid`` names the track; the owning EngineLoop sets it);
+        # scheduler.* and decoder.block spans on this engine's thread
+        # track (``pid`` names the track; the owning EngineLoop sets
+        # it; the thread-track spans reach a profiler capture without
+        # a tracer too);
         # ``telemetry`` accumulates the per-block BlockStats the decoder
         # harvests; ``block_hist`` observes per-block wall time.
         self.tracer = tracer
@@ -680,51 +682,69 @@ class BlockScheduler:
             # prefill pool: admit (prefill publishes chunk KV to the
             # shared store), dismantle into handoff_ready, then admit
             # again so slots freed by the extraction fill this tick
-            self._admit()
-            self._extract_handoffs()
-            self._admit()
-            self._extract_handoffs()
+            for _ in range(2):
+                with span(self.tracer, "scheduler.admit", pid=self.pid):
+                    self._admit()
+                self._extract_handoffs()
             self.last_decoded_rows = 0
             return chunks, completions
-        self._merge_stragglers()
-        self._admit()
+        merges = self.merges
+        with span(self.tracer, "scheduler.merge", pid=self.pid) as sp:
+            self._merge_stragglers()
+            sp.annotate(merges=self.merges - merges)
+        with span(self.tracer, "scheduler.admit", pid=self.pid):
+            self._admit()
         # rows whose decode this tick actually pays for — sampled before
         # the decode loop so occupancy isn't attributed post-compaction
         self.last_decoded_rows = self.live_rows
         for gang in self.gangs:
             size0 = self.jit_cache_size()
+            st = gang.state
             t0_ns = time.perf_counter_ns()
-            gang.decoder.decode_block(gang.state)
+            # the block on this engine's track and in a profiler
+            # capture; its steps and commits are known only at the end
+            with span(self.tracer, "decoder.block", pid=self.pid,
+                      batch=st.batch, live=int((~st.done).sum()),
+                      block=st.block_idx,
+                      prompt_len=st.prompt_len) as sp:
+                gang.decoder.decode_block(st)
+                if st.block_stats:
+                    last = st.block_stats[-1]
+                    sp.annotate(steps=last.steps,
+                                committed=last.tokens_committed)
             t1_ns = time.perf_counter_ns()
             self.decode_wall_s += (t1_ns - t0_ns) / 1e9
             self.compile_watch.observe(
                 self.jit_cache_size() - size0, (t1_ns - t0_ns) / 1e9,
                 "decode_block", tracer=self.tracer, pid=self.pid,
                 t0_ns=t0_ns, t1_ns=t1_ns)
-            self._drain_block_stats(gang, t0_ns, t1_ns)
-            c, comp = self._harvest(gang, gang.state.nfe - gang.nfe_seen,
-                                    gang.state.host_syncs - gang.syncs_seen,
-                                    gang.state.logit_syncs
-                                    - gang.logit_syncs_seen,
-                                    t0_ns=t0_ns, t1_ns=t1_ns)
+            self._drain_block_stats(gang)
+            with span(self.tracer, "scheduler.harvest", pid=self.pid) as sp:
+                c, comp = self._harvest(
+                    gang, gang.state.nfe - gang.nfe_seen,
+                    gang.state.host_syncs - gang.syncs_seen,
+                    gang.state.logit_syncs - gang.logit_syncs_seen,
+                    t0_ns=t0_ns, t1_ns=t1_ns)
+                sp.annotate(chunks=len(c), completions=len(comp))
             gang.nfe_seen = gang.state.nfe
             gang.syncs_seen = gang.state.host_syncs
             gang.logit_syncs_seen = gang.state.logit_syncs
             chunks.extend(c)
             completions.extend(comp)
-        self._compact()
+        with span(self.tracer, "scheduler.compact", pid=self.pid):
+            self._compact()
         # backfill freed slots within the same tick so the next tick
         # decodes at full occupancy
-        self._admit()
+        with span(self.tracer, "scheduler.admit", pid=self.pid):
+            self._admit()
         return chunks, completions
 
-    def _drain_block_stats(self, gang: Gang, t0_ns: int,
-                           t1_ns: int) -> None:
+    def _drain_block_stats(self, gang: Gang) -> None:
         """Route the BlockStats the decoder just appended: into the
-        telemetry aggregator, the block-wall histogram, and a
-        thread-track trace span for this engine's timeline. Drained
-        every tick so compaction (which builds fresh states) never
-        loses or double-counts a block."""
+        telemetry aggregator and the block-wall histogram (the
+        ``decoder.block`` span in ``tick`` carries the block to the
+        tracer). Drained every tick so compaction (which builds fresh
+        states) never loses or double-counts a block."""
         stats = gang.state.block_stats
         gang.last_commit_conf = None
         if not stats:
@@ -736,13 +756,6 @@ class BlockScheduler:
         if self.block_hist is not None:
             for bs in stats:
                 self.block_hist.observe(bs.wall_s)
-        if self.tracer is not None:
-            last = stats[-1]
-            self.tracer.complete(
-                "decode_block", t0_ns, t1_ns, pid=self.pid,
-                method=last.method, block=last.block_idx,
-                batch=last.batch, steps=last.steps,
-                committed=last.tokens_committed)
 
     # ------------------------------------------------------ admission
 
@@ -856,8 +869,8 @@ class BlockScheduler:
             cache = None
             if decoder.dcfg.method != "vanilla":
                 cache = self.pool.acquire(padded, P + gen_len)
-            with span(self.tracer, "prefill", pid=self.pid, batch=padded,
-                      prompt_len=P):
+            with span(self.tracer, "scheduler.prefill", pid=self.pid,
+                      batch=padded, prompt_len=P):
                 return decoder.prefill(prompts, cache=cache)
 
         t0 = time.perf_counter()

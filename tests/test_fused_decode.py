@@ -164,3 +164,40 @@ def test_decode_state_resume_across_loop_switch():
     out = dec_f.finalize(st)
     assert (out.tokens == ref.tokens).all()
     assert out.nfe == ref.nfe
+
+
+def test_block_program_names_its_phases():
+    """The fused block program is ``jit_decode_block``, and its
+    compiled operations carry the phase scopes a device trace reads."""
+    import re
+    d = DecodeConfig(method="streaming", gen_len=16, block_size=8, window=4,
+                     fused=True)
+    dec = DiffusionDecoder(CFG, PARAMS, d)
+    lowered = dec.lower_block(dec.prefill(PROMPT.copy()))
+    assert lowered.as_text().startswith("module @jit_decode_block")
+    op_names = set(re.findall(r'op_name="([^"]*)"',
+                              lowered.compile().as_text()))
+    for scope in ("jit(decode_block)/refresh/",
+                  "jit(decode_block)/while/body/denoise_step/",
+                  "/refresh/head_confidence/",
+                  "/denoise_step/head_confidence/",
+                  "jit(decode_block)/finalize/"):
+        assert any(scope in n for n in op_names), scope
+
+
+def test_jitted_functions_are_named_after_their_keys():
+    """Every jitted decoder function, and the executor's cache maker,
+    is named for what it does, never ``f`` or ``<lambda>``."""
+    from repro.launch.mesh import make_submeshes
+    from repro.serving import DecodeExecutor
+    dec = DiffusionDecoder(CFG, PARAMS, DecodeConfig(method="streaming"))
+    makers = [getattr(dec, a) for a in dir(dec)
+                if a.startswith("_") and a.endswith("_fn")]
+    for make in makers:
+        make()
+    assert len(dec._fns) == len(makers) == 13
+    for key, fn in dec._fns.items():
+        assert fn.__name__ == key
+    ex = DecodeExecutor(CFG, PARAMS, make_submeshes(1)[0])
+    ex.init_cache(1, 16)
+    assert [fn.__name__ for fn in ex._cache_fns.values()] == ["init_cache"]

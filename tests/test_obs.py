@@ -77,6 +77,113 @@ def test_tracer_null_span_helper():
     assert any(e.get("name") == "kept" for e in tr.events())
 
 
+def _capture(tmp_path, work):
+    """Host events of a CPU ``jax.profiler`` capture around ``work``:
+    ``{name: [(start_ns, end_ns, stats), ...]}`` from ``/host:CPU``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats)))
+    return out
+
+
+def test_span_lands_in_profiler_capture(tmp_path):
+    """One call, two sinks: inside a capture, ``span(None, ...)`` and
+    ``span(tracer, ...)`` both land on the host plane with their args
+    (``pid`` left out), nested as entered, with ``annotate`` args
+    added at the end; ``span(tracer, ...)`` still fills the ring."""
+    tr = Tracer()
+    pid = tr.process("engine-0")
+
+    def work():
+        with span(tr, "decoder.block", pid=pid, batch=8, live=3) as blk:
+            with span(None, "decoder.sync"):
+                time.sleep(0.002)
+            blk.annotate(steps=5, committed=7)
+        with span(None, "loop.wait") as w:
+            w.annotate(note="idle")
+
+    evs = _capture(tmp_path, work)
+    ((b0, b1, bst),) = evs["decoder.block"]
+    ((s0, s1, sst),) = evs["decoder.sync"]
+    assert bst == {"batch": 8, "live": 3, "steps": 5, "committed": 7}
+    assert sst == {}
+    assert b0 <= s0 < s1 <= b1 and s1 - s0 >= 1.5e6
+    assert evs["loop.wait"][0][2] == {"note": "idle"}
+    (ring,) = [e for e in tr.events() if e.get("ph") == "X"]
+    assert ring["name"] == "decoder.block" and ring["pid"] == pid
+    assert ring["args"] == {"batch": 8, "live": 3, "steps": 5,
+                            "committed": 7}
+    assert ring["dur"] >= 1500
+
+
+def test_block_profiler_captures_spans_without_python_tracing(tmp_path):
+    """``--profile-blocks``' capture holds the program's spans and no
+    Python function events (``$file.py:line name``), which would slow
+    the host path between blocks it is there to show."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.obs.profiler import BlockProfiler
+    prof = BlockProfiler(str(tmp_path), n_blocks=2)
+    prof.tick(1)                                 # starts the capture
+    assert prof.active
+    with span(None, "scheduler.admit"):
+        sum(range(100))
+    prof.tick(1)                                 # second block: stops
+    assert prof.done and not prof.active
+    (path,) = glob.glob(str(tmp_path / "jax_profile" / "**" /
+                            "*.xplane.pb"), recursive=True)
+    names = [ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events]
+    assert "scheduler.admit" in names
+    assert not [n for n in names if ".py:" in n]
+
+
+def test_scheduler_tick_spans_in_profiler_capture(tmp_path):
+    """A served block with no tracer attached still shows the block
+    boundary in a capture: ``decoder.block`` with its shape and, at
+    exit, steps and commits; the decoder's three sub-spans inside it;
+    the scheduler's and the engine's spans around it."""
+    eng = _engine(max_slots=2)
+    eng.submit(PROMPT, max_tokens=16)
+
+    def work():
+        while not eng.scheduler.idle:
+            eng.step()
+
+    evs = _capture(tmp_path, work)
+    for name in ("scheduler.merge", "scheduler.admit", "scheduler.prefill",
+                 "scheduler.harvest", "scheduler.compact", "engine.publish",
+                 "decoder.inputs", "decoder.dispatch", "decoder.sync"):
+        assert name in evs, name
+    blocks = evs["decoder.block"]
+    assert len(blocks) == 2
+    for (b0, b1, st), sync in zip(blocks, evs["decoder.sync"]):
+        assert {"batch", "live", "block", "prompt_len", "steps",
+                "committed"} <= set(st)
+        assert 1 <= st["steps"] <= 8 and b0 <= sync[0] < sync[1] <= b1
+    assert [st["block"] for _, _, st in blocks] == [0, 1]
+
+
 def test_tracer_ring_capacity_drops_oldest():
     tr = Tracer(capacity_per_thread=8)
     for i in range(20):
