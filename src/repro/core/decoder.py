@@ -34,12 +34,17 @@ Two execution paths for the per-block denoise loop:
       token identity) and as the baseline ``benchmarks/bench_decode.py``
       measures against.
 
-Query shapes are exact per block, so the jit cache holds at most
-#distinct-(block, batch)-shapes entries in either path.
+Each row of a batch carries its own block index. The fused program
+takes the block starts as data and runs a batch's denoise steps over the
+widest query region among its rows (the earliest row's), so rows at
+different blocks share one program: its jit key is (batch, query width)
+for the methods that mix (``DiffusionDecoder.mixes_blocks``), and the
+other methods keep every row of a batch at one block.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Any, Dict, Optional
 
@@ -50,8 +55,9 @@ import numpy as np
 from repro.core import schedule as sched
 from repro.core.suffix import suffix_query_region
 from repro.kernels import ops as kops
-from repro.models.config import ModelConfig
-from repro.models.model import apply_model, cache_take_rows, init_cache
+from repro.models.config import ATTN, ATTN_LOCAL, MOE, ModelConfig
+from repro.models.model import (apply_model, cache_take_rows, head_logits,
+                                head_weight, init_cache)
 from repro.obs.telemetry import CONF_BUCKETS, BlockStats
 from repro.obs.trace import span
 
@@ -135,19 +141,19 @@ class DecodeConfig:
 
 @dataclasses.dataclass
 class DecodeState:
-    """Resumable decode progress for a batch of rows that all sit at the
-    same block boundary. Produced by ``DiffusionDecoder.prefill`` and
-    advanced one diffusion block at a time by ``decode_block`` — the
-    host-side contract the continuous-batching scheduler
-    (``repro.serving``) is built on: between any two blocks the
-    scheduler may harvest finished rows, compact the batch, or
-    interleave other requests' states on the same compiled step fns."""
+    """Resumable decode progress for a batch of rows, each at a block
+    boundary of its own (``blocks``). Produced by
+    ``DiffusionDecoder.prefill`` and advanced one diffusion block per
+    row at a time by ``decode_block`` — the host-side contract the
+    continuous-batching scheduler (``repro.serving``) is built on:
+    between any two blocks the scheduler may harvest finished rows,
+    compact the batch, or merge other requests' rows into it."""
     x: np.ndarray                     # (B, T) tokens; mask id where open
     committed: np.ndarray             # (B, T) bool
     done: np.ndarray                  # (B,) early-exited rows
     prompt_len: int
     n_blocks: int
-    block_idx: int = 0                # next block to decode
+    blocks: Optional[np.ndarray] = None   # (B,) next block of each row
     cache: Any = None
     valid_mask: Optional[np.ndarray] = None    # dkv only: (B, T) bool
     cached_mask: Optional[np.ndarray] = None   # dkv only: (B, T) bool
@@ -168,6 +174,10 @@ class DecodeState:
     # decoder users read it off the finished state.
     block_stats: list = dataclasses.field(default_factory=list)
 
+    def __post_init__(self):
+        if self.blocks is None:
+            self.blocks = np.zeros((self.batch,), np.int32)
+
     @property
     def batch(self) -> int:
         return self.x.shape[0]
@@ -177,11 +187,28 @@ class DecodeState:
         return self.x.shape[1]
 
     @property
+    def live(self) -> np.ndarray:
+        """(B,) rows that still have a block to decode."""
+        return ~self.done & (self.blocks < self.n_blocks)
+
+    @property
+    def block_idx(self) -> int:
+        """The earliest live row's next block (every row's, once none
+        is live). Assigning an int puts every row at that block."""
+        live = self.live
+        return int(self.blocks[live].min() if live.any()
+                   else self.blocks.max())
+
+    @block_idx.setter
+    def block_idx(self, b: int) -> None:
+        self.blocks = np.full((self.batch,), b, np.int32)
+
+    @property
     def finished(self) -> bool:
-        return self.block_idx >= self.n_blocks or bool(self.done.all())
+        return not self.live.any()
 
     def row_finished(self, b: int) -> bool:
-        return bool(self.done[b]) or self.block_idx >= self.n_blocks
+        return not self.live[b]
 
 
 @dataclasses.dataclass
@@ -219,7 +246,6 @@ class DiffusionDecoder:
         # within the request) but nothing is shared across requests.
         self.prompt_cache = prompt_cache
         if dcfg.prefix_cache:
-            from repro.models.config import ATTN, ATTN_LOCAL
             assert all(s.mixer in (ATTN, ATTN_LOCAL) for s in cfg.layout), \
                 ("prefix_cache needs an attention-only layout (recurrent "
                  "states have no chunkable time axis)")
@@ -255,7 +281,7 @@ class DiffusionDecoder:
     # ------------------------------------------------------ shared pieces
 
     def _head(self, p):
-        return p["embed"].T if self.cfg.tie_embeddings else p["lm_head"]
+        return head_weight(self.cfg, p)
 
     def _conf_from_hidden(self, p, h_blk):
         """Fused head path (parallel methods): hidden (B, K, d) ->
@@ -502,6 +528,24 @@ class DiffusionDecoder:
         return self.dcfg.method != "dkv"
 
     @property
+    def mixes_blocks(self) -> bool:
+        """True when one ``decode_block`` call may advance rows that
+        stand at different block indexes — the property the scheduler
+        reads to merge gangs across blocks. Holds for the cached methods
+        whose block-start refresh runs over the whole sequence under a
+        per-row key mask (prefix, fast, streaming) in the fused block
+        program, on a layout whose tokens interact only through
+        attention: a recurrent state or a capacity-bound MoE router
+        would see the masked tokens. The host loop, the prefix-cache and
+        frozen-suffix refreshes, dkv and vanilla keep every row of a
+        batch at one block."""
+        d = self.dcfg
+        return (d.fused and d.method in ("prefix", "fast", "streaming")
+                and not d.prefix_cache and not d.frozen_suffix
+                and all(s.mixer in (ATTN, ATTN_LOCAL) and s.ffn != MOE
+                        for s in self.cfg.layout))
+
+    @property
     def cache_carries_state(self) -> bool:
         """True when the KV buffer holds state a block refresh does NOT
         rewrite — dkv's position-indexed cache, or the prefix-cached
@@ -687,7 +731,7 @@ class DiffusionDecoder:
         sub = DecodeState(
             x=state.x[rows].copy(), committed=state.committed[rows].copy(),
             done=state.done[rows].copy(), prompt_len=state.prompt_len,
-            n_blocks=state.n_blocks, block_idx=state.block_idx,
+            n_blocks=state.n_blocks, blocks=state.blocks[rows].copy(),
             steps_per_block=list(state.steps_per_block))
         if state.prefix_hit_tokens is not None:
             sub.prefix_hit_tokens = state.prefix_hit_tokens[rows].copy()
@@ -714,9 +758,10 @@ class DiffusionDecoder:
         return sub
 
     def merge_rows(self, parts, cache: Any = None) -> DecodeState:
-        """Fuse rows from several states sitting at the SAME block
-        boundary into one state (the scheduler's cross-gang straggler
-        merge). ``parts`` is a list of ``(state, rows)``. Requires
+        """Fuse rows from several states of one shape bucket into one
+        state (the scheduler's cross-gang merge). ``parts`` is a list of
+        ``(state, rows)``; the rows may stand at different block indexes
+        where ``mixes_blocks`` holds, else all at one. Requires
         ``batch_invariant`` (per-row results don't depend on batching)
         and excludes dkv, whose cache carries across blocks; for every
         other cached method the next block refresh rewrites the cache,
@@ -724,21 +769,23 @@ class DiffusionDecoder:
         assert self.batch_invariant and self.dcfg.method != "dkv"
         ref = parts[0][0]
         for st, _ in parts[1:]:
-            assert (st.prompt_len, st.n_blocks, st.block_idx) == \
-                (ref.prompt_len, ref.n_blocks, ref.block_idx), \
-                "cross-gang merge requires identical (bucket, block) state"
+            assert (st.prompt_len, st.n_blocks) == \
+                (ref.prompt_len, ref.n_blocks), \
+                "cross-gang merge requires one shape bucket"
+        blocks = np.concatenate([st.blocks[rows] for st, rows in parts])
+        assert self.mixes_blocks or len(set(blocks.tolist())) == 1, \
+            "this method decodes a batch at one block index"
         sub = DecodeState(
             x=np.concatenate([st.x[rows] for st, rows in parts]),
             committed=np.concatenate(
                 [st.committed[rows] for st, rows in parts]),
             done=np.concatenate([st.done[rows] for st, rows in parts]),
             prompt_len=ref.prompt_len, n_blocks=ref.n_blocks,
-            block_idx=ref.block_idx,
+            blocks=blocks,
             # per-block step counts diverge across source gangs; keep
             # the elementwise max (metrics-only, like take_rows' copy)
-            steps_per_block=[max(vals) for vals in zip(
-                *(st.steps_per_block for st, _ in parts))]
-            if ref.steps_per_block else [])
+            steps_per_block=[max(vals) for vals in itertools.zip_longest(
+                *(st.steps_per_block for st, _ in parts), fillvalue=0)])
         if all(st.prefix_hit_tokens is not None for st, _ in parts):
             sub.prefix_hit_tokens = np.concatenate(
                 [st.prefix_hit_tokens[rows] for st, rows in parts])
@@ -769,20 +816,21 @@ class DiffusionDecoder:
     # ------------------------------------------------------ block step
 
     def decode_block(self, state: DecodeState) -> DecodeState:
-        """Run the full denoise loop for ``state.block_idx`` and advance
-        to the next block boundary (mutates and returns ``state``).
-        No-op on a finished state."""
+        """Decode the next block of every row that has one, each at its
+        own block index, and advance those rows to their next block
+        boundary (mutates and returns ``state``). No-op on a finished
+        state."""
         if state.finished:
             return state
         if self.dcfg.fused:
             return self._decode_block_fused(state)
         return self._decode_block_host(state)
 
-    def _query_region(self, state: DecodeState):
+    def _region(self, prompt_len: int, block_idx: int):
         d = self.dcfg
         region = suffix_query_region(
-            gen_start=state.prompt_len, gen_len=d.gen_len,
-            block_size=d.block_size, block_idx=state.block_idx,
+            gen_start=prompt_len, gen_len=d.gen_len,
+            block_size=d.block_size, block_idx=block_idx,
             window=d.effective_window if d.trailing_position
             else max(d.effective_window, 0))
         qpos = region.positions                       # (Sq,)
@@ -796,9 +844,12 @@ class DiffusionDecoder:
         """The device-resident per-block denoise loop: refresh (where the
         method has one) + a ``lax.while_loop`` over denoise steps +
         straggler finalize + EOS early exit, compiled as ONE function.
-        Specialized per (method, shapes, bstart); the host calls it once
-        per block and syncs once on its outputs. Its phases carry named
-        scopes, so a device trace can tell them apart: ``refresh`` (the
+        Each row's block comes in as data (``bidx_b``), so the program
+        is specialized per (method, batch, query width) — and, for the
+        methods that keep a batch at one block, per refresh ``prefix``
+        (None where ``mixes_blocks``). The host calls it once per block
+        and syncs once on its outputs. Its phases carry named scopes,
+        so a device trace can tell them apart: ``refresh`` (the
         block-start pass with its confidence and commit),
         ``denoise_step`` (the ``while_loop`` body), ``head_confidence``
         (inside both) and ``finalize`` (straggler fill, early exit)."""
@@ -807,38 +858,76 @@ class DiffusionDecoder:
         cfg, d = self.cfg, self.dcfg
         eos_id = cfg.eos_token_id   # the [MASK] ban lives in _conf_from_*
         K = d.block_size
+        n_blocks = d.gen_len // K
         steps_cap = d.steps_per_block or K
         n_commit = max(1, K // steps_cap)
         uk = d.use_kernels
         parallel = d.parallel
         frozen = d.frozen_suffix and parallel
 
-        def commit_tokens(x, committed, conf, toks, bstart):
-            """Eq. 9/fixed-rate selection + token write for one step.
-            Mirrors the host loop exactly (all rows participate; only
-            the loop CONDITION excludes early-exited rows)."""
-            B = x.shape[0]
-            blk_committed = committed[:, bstart:bstart + K]
-            blk_masked = ~blk_committed
-            if parallel:
-                if d.method == "streaming":
-                    r_mask = jnp.mean(blk_masked.astype(jnp.float32), axis=1)
-                    tau = sched.dynamic_threshold(d.tau0, d.alpha, r_mask)
-                else:
-                    tau = jnp.full((B,), d.tau0, jnp.float32)
-                commit = sched.select_tokens(conf, blk_masked, tau)
-            else:
-                commit = sched.fixed_rate_select(conf, blk_masked, n_commit)
-            new_blk = jnp.where(commit, toks, x[:, bstart:bstart + K])
-            x = x.at[:, bstart:bstart + K].set(new_blk)
-            committed = committed.at[:, bstart:bstart + K].set(
-                blk_committed | commit)
-            return x, committed, commit
-
-        def decode_block(p, x, committed, done, cache, qpos_b, valid_mask,
-                         cached_mask, *, bstart, pstart):
+        def decode_block(p, x, committed, done, cache, qpos_b, bidx_b,
+                         valid_mask, cached_mask, *, prefix, pstart):
             B, T = x.shape
-            prefix_len = bstart
+            # a row past its last block rides along as a done lane, at
+            # its last block (already committed, so nothing changes)
+            dead = done | (bidx_b >= n_blocks)
+            bstart_b = T - d.gen_len + jnp.minimum(bidx_b, n_blocks - 1) * K
+            # a narrower row's spare query slots (-1) stand at its block
+            # start; the steps keep them out of every query's keys
+            qvalid_b = qpos_b >= 0
+            qpos_b = jnp.where(qvalid_b, qpos_b, bstart_b[:, None])
+
+            # Each row's block is picked from the generation region
+            # viewed as (B, n_blocks, K, ...) by a one-hot over the block
+            # axis: selects and a reduction, where a per-row
+            # dynamic_slice would be a gather or scatter that XLA:TPU
+            # runs as a serial loop.
+            g0 = T - d.gen_len
+            onehot = (jnp.arange(n_blocks)[None]
+                      == jnp.minimum(bidx_b, n_blocks - 1)[:, None])
+
+            def blocks_of(a):
+                g = a[:, g0:g0 + n_blocks * K]
+                g = g.reshape((B, n_blocks, K) + a.shape[2:])
+                return g, onehot.reshape((B, n_blocks)
+                                         + (1,) * (g.ndim - 2))
+
+            def blk(a):
+                """Each row's current block of a (B, T, ...) array."""
+                g, m = blocks_of(a)
+                if a.dtype == jnp.bool_:
+                    return jnp.any(g & m, axis=1)
+                # one term per row is nonzero: the sum is exact
+                return jnp.sum(jnp.where(m, g, jnp.zeros((), a.dtype)),
+                               axis=1)
+
+            def set_blk(a, v):
+                g, m = blocks_of(a)
+                g = jnp.where(m, v[:, None], g)
+                return a.at[:, g0:g0 + n_blocks * K].set(
+                    g.reshape((B, n_blocks * K) + a.shape[2:]))
+
+            def commit_tokens(x, committed, conf, toks):
+                """Eq. 9/fixed-rate selection + token write for one step.
+                Mirrors the host loop exactly (all rows participate; only
+                the loop CONDITION excludes early-exited rows)."""
+                blk_committed = blk(committed)
+                blk_masked = ~blk_committed
+                if parallel:
+                    if d.method == "streaming":
+                        r_mask = jnp.mean(blk_masked.astype(jnp.float32),
+                                          axis=1)
+                        tau = sched.dynamic_threshold(d.tau0, d.alpha, r_mask)
+                    else:
+                        tau = jnp.full((B,), d.tau0, jnp.float32)
+                    commit = sched.select_tokens(conf, blk_masked, tau)
+                else:
+                    commit = sched.fixed_rate_select(conf, blk_masked,
+                                                     n_commit)
+                x = set_blk(x, jnp.where(commit, toks, blk(x)))
+                committed = set_blk(committed, blk_committed | commit)
+                return x, committed, commit
+
             vsums = jnp.zeros((steps_cap,), jnp.int32)  # dkv kv-size trace
             # telemetry carries (repro.obs): commits per device step and
             # a confidence histogram of committed tokens — scatter-adds
@@ -854,7 +943,7 @@ class DiffusionDecoder:
             # block's other outputs — same single host sync.
             cconf = jnp.zeros((B, K), jnp.float32)
             lconf = jnp.zeros((B, K), jnp.float32)
-            live = ~done[:, None]
+            live = ~dead[:, None]
 
             def tally(counts, hist, step, commit, conf):
                 act = commit & live
@@ -867,9 +956,7 @@ class DiffusionDecoder:
                 return counts, hist
 
             def loop_open(committed, step):
-                blk_masked = ~committed[:, bstart:bstart + K]
-                return ((step < steps_cap)
-                        & jnp.any(blk_masked & ~done[:, None]))
+                return (step < steps_cap) & jnp.any(~blk(committed) & live)
 
             if d.method == "vanilla":
                 pos_T = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
@@ -883,10 +970,9 @@ class DiffusionDecoder:
                     x, committed, step, _, counts, hist, cconf, _ = c
                     out = apply_model(cfg, p, tokens=x, positions=pos_T,
                                       use_kernels=uk)
-                    conf, toks = self._conf_from_logits(
-                        out.logits[:, bstart:bstart + K])
+                    conf, toks = self._conf_from_logits(blk(out.logits))
                     x, committed, commit = commit_tokens(
-                        x, committed, conf, toks, bstart)
+                        x, committed, conf, toks)
                     counts, hist = tally(counts, hist, step, commit, conf)
                     cconf = jnp.where(commit, conf, cconf)
                     return (x, committed, step + 1, toks, counts, hist,
@@ -923,7 +1009,7 @@ class DiffusionDecoder:
                     vsums = vsums.at[step].set(
                         jnp.sum(valid_mask.astype(jnp.int32)) // B)
                     x, committed, commit = commit_tokens(
-                        x, committed, conf, toks, bstart)
+                        x, committed, conf, toks)
                     counts, hist = tally(counts, hist, step, commit, conf)
                     cconf = jnp.where(commit, conf, cconf)
                     return (x, committed, step + 1, toks, out.cache,
@@ -941,60 +1027,81 @@ class DiffusionDecoder:
             else:
                 # prefix / fast / streaming: block-start refresh (paper
                 # §3.3) outside the loop — it has a different query shape
-                # and is the only step that writes the cache. With
-                # prefix_cache the pass starts at the prompt boundary
-                # (pstart): the prompt KV was computed at prefill and is
-                # attended via kv_valid, never recomputed.
+                # and is the only step that writes the cache.
                 with jax.named_scope("refresh"):
-                    p0 = pstart if d.prefix_cache else 0
-                    pref_pos = jnp.broadcast_to(
-                        jnp.arange(p0, prefix_len, dtype=jnp.int32)[None],
-                        (B, prefix_len - p0))
-                    full_pos = jnp.concatenate([pref_pos, qpos_b], axis=1)
-                    full_toks = jnp.take_along_axis(x, full_pos, axis=1)
-                    if d.prefix_cache:
-                        out = apply_model(cfg, p, tokens=full_toks,
-                                          positions=full_pos, mode="append",
-                                          cache=cache,
-                                          kv_valid=jnp.full((B,), pstart,
-                                                            jnp.int32),
-                                          skip_head=parallel, use_kernels=uk)
-                        valid = jnp.full((B,), prefix_len, jnp.int32)
-                    elif frozen:
-                        out = apply_model(cfg, p, tokens=full_toks,
-                                          positions=full_pos, mode="append",
-                                          cache=cache,
-                                          kv_valid=jnp.zeros((B,), jnp.int32),
-                                          append_at=full_pos,
-                                          cache_upto=prefix_len,
-                                          skip_head=True, use_kernels=uk)
-                        valid = jnp.broadcast_to(
-                            jnp.arange(T) < prefix_len, (B, T))
-                        valid = valid.at[jnp.arange(B)[:, None],
-                                         qpos_b[:, K:]].set(True)
+                    if prefix is None:
+                        # one width for every row: the whole sequence at
+                        # its absolute positions, each row keeping as
+                        # keys just its own prefix and query region, so
+                        # each kept query attends what it would attend
+                        # in a pass over [prefix || query region] alone
+                        pos_T = jnp.broadcast_to(
+                            jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+                        keys = (pos_T < bstart_b[:, None]) | jnp.any(
+                            pos_T[:, :, None] == qpos_b[:, None, :],
+                            axis=2)
+                        out = apply_model(cfg, p, tokens=x, positions=pos_T,
+                                          mode="encode", cache=cache,
+                                          self_mask=keys, skip_head=True,
+                                          use_kernels=uk)
+                        blk_out = blk(out.logits)
+                        if not parallel:
+                            blk_out = head_logits(cfg, p, blk_out)
+                        valid = bstart_b
                     else:
-                        out = apply_model(cfg, p, tokens=full_toks,
-                                          positions=full_pos, mode="encode",
-                                          cache=cache, cache_upto=prefix_len,
-                                          skip_head=parallel, use_kernels=uk)
-                        valid = jnp.full((B,), prefix_len, jnp.int32)
+                        # one block for the whole batch, at ``prefix``.
+                        # With prefix_cache the pass starts at the
+                        # prompt boundary (pstart): the prompt KV was
+                        # computed at prefill and is attended via
+                        # kv_valid, never recomputed.
+                        p0 = pstart if d.prefix_cache else 0
+                        pref_pos = jnp.broadcast_to(
+                            jnp.arange(p0, prefix, dtype=jnp.int32)[None],
+                            (B, prefix - p0))
+                        full_pos = jnp.concatenate([pref_pos, qpos_b],
+                                                   axis=1)
+                        full_toks = jnp.take_along_axis(x, full_pos, axis=1)
+                        if d.prefix_cache:
+                            out = apply_model(
+                                cfg, p, tokens=full_toks,
+                                positions=full_pos, mode="append",
+                                cache=cache,
+                                kv_valid=jnp.full((B,), pstart, jnp.int32),
+                                skip_head=parallel, use_kernels=uk)
+                            valid = bstart_b
+                        elif frozen:
+                            out = apply_model(
+                                cfg, p, tokens=full_toks,
+                                positions=full_pos, mode="append",
+                                cache=cache,
+                                kv_valid=jnp.zeros((B,), jnp.int32),
+                                append_at=full_pos, cache_upto=prefix,
+                                skip_head=True, use_kernels=uk)
+                            valid = jnp.broadcast_to(
+                                jnp.arange(T) < prefix, (B, T))
+                            valid = valid.at[jnp.arange(B)[:, None],
+                                             qpos_b[:, K:]].set(True)
+                        else:
+                            out = apply_model(
+                                cfg, p, tokens=full_toks,
+                                positions=full_pos, mode="encode",
+                                cache=cache, cache_upto=prefix,
+                                skip_head=parallel, use_kernels=uk)
+                            valid = bstart_b
+                        boff = prefix - p0
+                        blk_out = out.logits[:, boff:boff + K]
                     cache = out.cache
-                    boff = prefix_len - p0
-                    blk_out = out.logits[:, boff:boff + K]
                     if parallel:
                         conf, toks = self._conf_from_hidden(p, blk_out)
                     else:
                         conf, toks = self._conf_from_logits(blk_out)
                     x, committed, commit = commit_tokens(x, committed, conf,
-                                                         toks, bstart)
+                                                         toks)
                     counts, hist = tally(counts, hist, 0, commit, conf)
                     cconf = jnp.where(commit, conf, cconf)
                     lconf = conf
 
-                if frozen:
-                    bpos = jnp.broadcast_to(
-                        jnp.arange(bstart, bstart + K,
-                                   dtype=jnp.int32)[None], (B, K))
+                step_keys = qvalid_b if prefix is None else None
 
                 def cond(c):
                     committed, step = c[1], c[2]
@@ -1004,8 +1111,9 @@ class DiffusionDecoder:
                 def body(c):
                     x, committed, step, _, counts, hist, cconf, _ = c
                     if frozen:
-                        out = apply_model(cfg, p,
-                                          tokens=x[:, bstart:bstart + K],
+                        bpos = bstart_b[:, None] + jnp.arange(
+                            K, dtype=jnp.int32)[None]
+                        out = apply_model(cfg, p, tokens=blk(x),
                                           positions=bpos, mode="step",
                                           cache=cache, kv_valid=valid,
                                           mesh=self.mesh,
@@ -1016,6 +1124,7 @@ class DiffusionDecoder:
                         out = apply_model(cfg, p, tokens=q_toks,
                                           positions=qpos_b, mode="step",
                                           cache=cache, kv_valid=valid,
+                                          self_mask=step_keys,
                                           mesh=self.mesh,
                                           data_axes=self.data_axes,
                                           skip_head=parallel,
@@ -1027,7 +1136,7 @@ class DiffusionDecoder:
                         conf, toks = self._conf_from_logits(
                             out.logits[:, :K])
                     x, committed, commit = commit_tokens(
-                        x, committed, conf, toks, bstart)
+                        x, committed, conf, toks)
                     counts, hist = tally(counts, hist, step, commit, conf)
                     cconf = jnp.where(commit, conf, cconf)
                     return (x, committed, step + 1, toks, counts, hist,
@@ -1042,18 +1151,17 @@ class DiffusionDecoder:
                 # straggler finalize (steps cap reached): commit the last
                 # step's argmax — but never overwrite rows that early-exited
                 # in a prior block (their tail is EOS-truncated territory)
-                blk = x[:, bstart:bstart + K]
-                blk_masked = ~committed[:, bstart:bstart + K]
-                fill = blk_masked & ~done[:, None] & (steps > 0)
+                blk_x = blk(x)
+                fill = ~blk(committed) & live & (steps > 0)
                 fill_n = jnp.sum(fill.astype(jnp.int32))
                 cconf = jnp.where(fill, lconf, cconf)
-                blk = jnp.where(fill, toks, blk)
-                x = x.at[:, bstart:bstart + K].set(blk)
-                committed = committed.at[:, bstart:bstart + K].set(True)
+                blk_x = jnp.where(fill, toks, blk_x)
+                x = set_blk(x, blk_x)
+                committed = set_blk(committed, jnp.ones((B, K), jnp.bool_))
                 # Early exit (paper §3.3): a block that decoded an EOS makes
                 # all *subsequent* blocks skippable for that row.
                 if d.early_exit:
-                    hit = jnp.any(blk == eos_id, axis=1) & ~done
+                    hit = jnp.any(blk_x == eos_id, axis=1) & ~dead
                     n_hit = jnp.sum(hit.astype(jnp.int32))
                     done = done | hit
                 else:
@@ -1078,14 +1186,43 @@ class DiffusionDecoder:
                           and self.executor.donate_cache
                           and d.method != "vanilla") else ()
         self._fns["decode_block"] = jax.jit(
-            decode_block, static_argnames=("bstart", "pstart"),
+            decode_block, static_argnames=("prefix", "pstart"),
             donate_argnums=donate)
         return self._fns["decode_block"]
 
-    def _fused_inputs(self, state: DecodeState, region, qpos):
+    def _plan(self, state: DecodeState):
+        """Where each row decodes in the next call: ``(run, eff, qpos)``
+        — the rows before their last block, each such row's block, and
+        the query positions of every block that occurs. A done row
+        behind the earliest live row rides at that row's block, so the
+        query width is always a live row's."""
+        n = state.n_blocks
+        run = state.blocks < n
+        live = run & ~state.done
+        b_min = int(state.blocks[live].min())
+        eff = np.where(live, state.blocks,
+                       np.clip(state.blocks, b_min, n - 1)).astype(np.int32)
+        if not self.mixes_blocks:
+            assert (eff == b_min).all(), (
+                f"method {self.dcfg.method!r} decodes a batch at one block "
+                f"index, got {sorted(set(eff.tolist()))}")
+        qpos = {b: self._region(state.prompt_len, b)[1]
+                for b in set(eff.tolist())}
+        return run, eff, qpos
+
+    def _fused_inputs(self, state: DecodeState, plan):
         """Device arguments and static kwargs of the fused fn for the
-        next block of ``state``."""
-        qpos_b = np.broadcast_to(qpos[None], (state.batch, len(qpos))).copy()
+        next block of ``state``: each row's query positions, padded to
+        the widest row's with -1, and each row's block (``n_blocks`` for
+        a row past its last)."""
+        run, eff, qpos = plan
+        d = self.dcfg
+        P, K = state.prompt_len, d.block_size
+        b_min = int(eff.min())
+        qpos_b = np.full((state.batch, len(qpos[b_min])), -1, np.int32)
+        for i, b in enumerate(eff.tolist()):
+            qpos_b[i, :len(qpos[b])] = qpos[b]
+        bidx_b = np.where(run, eff, state.n_blocks).astype(np.int32)
         vm = None if state.valid_mask is None \
             else self._put_batch(state.valid_mask)
         cm = None if state.cached_mask is None \
@@ -1093,18 +1230,18 @@ class DiffusionDecoder:
         args = (self.params, self._put_batch(state.x),
                 self._put_batch(state.committed),
                 self._put_batch(state.done), state.cache,
-                self._put_batch(qpos_b), vm, cm)
-        static = dict(bstart=region.block_start,
-                      pstart=state.prompt_len if self.dcfg.prefix_cache
-                      else 0)
+                self._put_batch(qpos_b), self._put_batch(bidx_b), vm, cm)
+        one_block = not self.mixes_blocks and d.method in ("prefix", "fast",
+                                                           "streaming")
+        static = dict(prefix=P + b_min * K if one_block else None,
+                      pstart=P if d.prefix_cache else 0)
         return args, static
 
     def lower_block(self, state: DecodeState):
         """AOT-lower the fused fn for the next block of ``state`` without
         running it — to inspect the compiled block program (which
         kernels it calls, its memory)."""
-        region, qpos = self._query_region(state)
-        args, static = self._fused_inputs(state, region, qpos)
+        args, static = self._fused_inputs(state, self._plan(state))
         return self._fused_fn().lower(*args, **static)
 
     def _decode_block_fused(self, state: DecodeState) -> DecodeState:
@@ -1116,17 +1253,15 @@ class DiffusionDecoder:
         steps_cap = d.steps_per_block or K
         frozen = d.frozen_suffix and d.parallel
 
-        region, qpos = self._query_region(state)
-        Sq = len(qpos)
-        prefix_len = region.block_start
-
-        live_rows = int((~state.done).sum())
+        run, eff, qpos = plan = self._plan(state)
+        live_rows = int(state.live.sum())
         # profiler-only spans (no tracer here): host->device puts, the
         # dispatch of the block program, and from the first readback
         # on, so a device trace can tell which host work its idle
         # gaps wait on
         with span(None, "decoder.inputs"):
-            args, static = self._fused_inputs(state, region, qpos)
+            args, static = self._fused_inputs(state, plan)
+        Sq = len(qpos[int(eff.min())])      # the gang's query width
         with span(None, "decoder.dispatch"):
             (x, committed, done, steps, n_hit, cache, vm, cm,
              vsums, counts, hist, fill_n, cconf) = self._fused_fn()(
@@ -1152,36 +1287,46 @@ class DiffusionDecoder:
 
             state.steps_per_block.append(steps)
             state.nfe += steps
-            if d.method == "vanilla":
-                state.q_tokens += steps * B * T
-                state.kv_tokens += steps * B * T * T
-            elif d.method == "dkv":
-                state.q_tokens += steps * B * Sq
-                for vs in np.asarray(vsums)[:steps]:
-                    state.kv_tokens += B * Sq * (int(vs) + Sq)
-            elif steps > 0:
-                # cached mode: the refresh pass covers only the generated
-                # prefix + query (the prompt is attended, not recomputed)
-                ref_q = (prefix_len - P if d.prefix_cache else prefix_len) + Sq
-                state.q_tokens += B * ref_q
-                state.kv_tokens += B * ref_q * (prefix_len + Sq)
-                if frozen:
-                    state.q_tokens += (steps - 1) * B * K
-                    state.kv_tokens += ((steps - 1) * B * K
-                                        * (prefix_len + Sq + K))
-                else:
-                    state.q_tokens += (steps - 1) * B * Sq
-                    state.kv_tokens += (steps - 1) * B * Sq * (prefix_len + Sq)
-            state.block_idx = region.block_idx + 1
+            # work each row's own block would cost alone (a narrower
+            # row's spare query slots are not counted)
+            for b, rows in zip(*np.unique(eff[run], return_counts=True)):
+                rows, prefix_len = int(rows), P + int(b) * K
+                sq = len(qpos[int(b)])
+                if d.method == "vanilla":
+                    state.q_tokens += steps * rows * T
+                    state.kv_tokens += steps * rows * T * T
+                elif d.method == "dkv":
+                    state.q_tokens += steps * rows * sq
+                    for vs in np.asarray(vsums)[:steps]:
+                        state.kv_tokens += rows * sq * (int(vs) + sq)
+                elif steps > 0:
+                    # cached mode: the refresh pass covers only the
+                    # generated prefix + query (the prompt is attended,
+                    # not recomputed)
+                    ref_q = (prefix_len - P if d.prefix_cache
+                             else prefix_len) + sq
+                    state.q_tokens += rows * ref_q
+                    state.kv_tokens += rows * ref_q * (prefix_len + sq)
+                    if frozen:
+                        state.q_tokens += (steps - 1) * rows * K
+                        state.kv_tokens += ((steps - 1) * rows * K
+                                            * (prefix_len + sq + K))
+                    else:
+                        state.q_tokens += (steps - 1) * rows * sq
+                        state.kv_tokens += ((steps - 1) * rows * sq
+                                            * (prefix_len + sq))
+            state.blocks = np.where(run, eff + 1,
+                                    state.blocks).astype(np.int32)
             wall = time.perf_counter() - t_block
             state.block_stats.append(BlockStats(
-                method=d.method, block_idx=region.block_idx, batch=B,
+                method=d.method, block_idx=int(eff.min()), batch=B,
                 live_rows=live_rows, steps=steps, steps_cap=steps_cap,
                 committed_per_step=[int(v) for v in counts[:steps]],
                 straggler_fill=int(fill_n),
                 conf_hist=[int(v) for v in hist],
                 window=Sq, early_exits=n_hit, wall_s=wall,
-                commit_conf=np.asarray(cconf, np.float32)))
+                commit_conf=np.asarray(cconf, np.float32),
+                row_blocks=np.where(run, eff, -1).tolist()))
             state.decode_time += wall
             return state
 
@@ -1207,7 +1352,10 @@ class DiffusionDecoder:
         nfe = q_tokens = kv_tokens = 0
 
         c = state.block_idx
-        region, qpos = self._query_region(state)
+        if (state.blocks[state.blocks < state.n_blocks] != c).any():
+            raise ValueError("the host loop decodes a batch at one block "
+                             "index")
+        region, qpos = self._region(P, c)
         Sq = len(qpos)
         qpos_b = np.broadcast_to(qpos[None], (B, Sq)).copy()
         bstart, bend = region.block_start, region.block_start + K

@@ -183,12 +183,15 @@ def attend_ref(q, k, v, *, scale, attn_softcap=0.0, window=0,
 
 def apply_attention(cfg, p, x, *, q_pos, kv_pos=None, kv_cache=None,
                     kv_valid=None, window=0, return_kv=False,
-                    self_kv_override=None, use_kernels=False):
+                    self_kv_override=None, self_mask=None,
+                    use_kernels=False):
     """GQA attention over [kv_cache || self].
 
     x: (B, Sq, d). kv_cache: optional (k, v) each (B, P, Hkv, D) with
     positions implicit in kv_pos (length P + Sq when cache present,
-    else Sq). ``use_kernels`` routes the attend to the Pallas
+    else Sq). ``self_mask`` (B, Sq) bool: which of this pass's own
+    tokens serve as keys (all of them when None); a masked token still
+    gets its output. ``use_kernels`` routes the attend to the Pallas
     flash-style kernel (``kernels.ops.block_attention``) instead of the
     chunked reference path — same GQA mapping, softcap, window, and KV
     validity semantics.
@@ -229,6 +232,12 @@ def apply_attention(cfg, p, x, *, q_pos, kv_pos=None, kv_cache=None,
             else:
                 idx = jnp.arange(P + Sq_self)[None, :]
                 kv_mask = (idx < kv_valid.reshape(-1, 1)) | (idx >= P)
+        if self_mask is not None:
+            own = jnp.concatenate([jnp.ones((B, P), jnp.bool_), self_mask],
+                                  axis=1)
+            kv_mask = own if kv_mask is None else kv_mask & own
+    elif self_mask is not None:
+        kv_mask = self_mask
     scale = cfg.attn_scale or (1.0 / math.sqrt(cfg.head_dim))
     if use_kernels:
         from repro.kernels import ops as kops

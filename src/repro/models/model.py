@@ -175,8 +175,8 @@ def _write_kv_at(buf, new, idx):
 
 def apply_layer(cfg, p, spec: LayerSpec, x, *, q_pos, cache, kv_valid,
                 mode, cache_positions=None, append_at=None,
-                self_kv_mix=None, cache_upto=None, mesh=None,
-                data_axes=("data",), use_kernels=False):
+                self_kv_mix=None, cache_upto=None, self_mask=None,
+                mesh=None, data_axes=("data",), use_kernels=False):
     """Returns (y, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -186,6 +186,7 @@ def apply_layer(cfg, p, spec: LayerSpec, x, *, q_pos, cache, kv_valid,
         if mode == "encode":
             out, kv = apply_attention(cfg, p["mixer"], h, q_pos=q_pos,
                                       window=window, return_kv=True,
+                                      self_mask=self_mask,
                                       use_kernels=use_kernels)
             if cache is not None:
                 zero = jnp.zeros((x.shape[0],), jnp.int32)
@@ -207,6 +208,7 @@ def apply_layer(cfg, p, spec: LayerSpec, x, *, q_pos, cache, kv_valid,
                                       kv_valid=kv_valid, window=window,
                                       return_kv=True,
                                       self_kv_override=override,
+                                      self_mask=self_mask,
                                       use_kernels=use_kernels)
             if mode == "append":
                 if append_at is not None:
@@ -268,18 +270,36 @@ def _seq_shard(x, mesh, data_axes):
 
 # ------------------------------------------------------------- forward
 
+def head_weight(cfg: ModelConfig, params):
+    """The output head, (d_model, vocab)."""
+    return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+
+
+def head_logits(cfg: ModelConfig, params, h):
+    """Float32 logits of final hidden states: the head projection and
+    the config's logit softcap."""
+    logits = (h @ head_weight(cfg, params).astype(h.dtype)).astype(
+        jnp.float32)
+    if cfg.logit_softcap:
+        logits = softcap(logits, cfg.logit_softcap)
+    return logits
+
+
 def apply_model(cfg: ModelConfig, params, *, tokens=None, embeds=None,
                 prefix_embeds=None,
                 positions=None, mode: str = "encode", cache=None,
                 kv_valid=None, cache_positions=None, append_at=None,
-                self_kv_mix=None, cache_upto=None, serve_long: bool = False,
+                self_kv_mix=None, cache_upto=None, self_mask=None,
+                serve_long: bool = False,
                 mesh=None, data_axes=("data",),
                 skip_head: bool = False,
                 use_kernels: bool = False) -> ModelOutput:
     """tokens: (B, S) int32 or embeds: (B, S, F|d). positions: (B, S).
     ``use_kernels`` routes attention layers to the Pallas block kernel
     (decode path; the reference path remains the training/autodiff
-    route)."""
+    route). ``self_mask`` (B, S) bool keeps only the marked tokens of
+    this pass as attention keys (attention layers; every token still
+    gets its output)."""
     dtype = _dtype(cfg.dtype)
     if tokens is not None:
         x = params["embed"][tokens].astype(dtype)
@@ -329,7 +349,8 @@ def apply_model(cfg: ModelConfig, params, *, tokens=None, embeds=None,
                                    cache_positions=cache_positions,
                                    append_at=append_at,
                                    self_kv_mix=self_kv_mix,
-                                   cache_upto=cache_upto, mesh=mesh,
+                                   cache_upto=cache_upto,
+                                   self_mask=self_mask, mesh=mesh,
                                    data_axes=data_axes,
                                    use_kernels=use_kernels)
             if cfg.remat:
@@ -366,7 +387,8 @@ def apply_model(cfg: ModelConfig, params, *, tokens=None, embeds=None,
                                cache_positions=cache_positions,
                                append_at=append_at,
                                self_kv_mix=self_kv_mix,
-                               cache_upto=cache_upto, mesh=mesh,
+                               cache_upto=cache_upto,
+                               self_mask=self_mask, mesh=mesh,
                                data_axes=data_axes,
                                use_kernels=use_kernels)
         aux = aux + a
@@ -376,10 +398,7 @@ def apply_model(cfg: ModelConfig, params, *, tokens=None, embeds=None,
     if skip_head:
         logits = x  # final hidden states; caller owns the head projection
     else:
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-        if cfg.logit_softcap:
-            logits = softcap(logits, cfg.logit_softcap)
+        logits = head_logits(cfg, params, x)
 
     new_cache = None
     if have_cache and mode != "step":

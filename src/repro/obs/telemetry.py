@@ -63,6 +63,10 @@ class BlockStats:
     # aggregated by _Agg — the per-request slices are consumed by the
     # scheduler harvest and dropped here.
     commit_conf: object = None
+    # (B,) block index each lane decoded (-1: a lane past its last
+    # block); lanes of one call may stand at different blocks, and
+    # ``block_idx`` is then the earliest of them
+    row_blocks: object = None
 
     @property
     def tokens_committed(self) -> int:

@@ -356,6 +356,9 @@ class ContinuousEngine:
         self.stats["time_s"] += dt
         self.metrics.queue_depth = len(self.scheduler.waiting)
         self.metrics.gang_merges = self.scheduler.merges
+        self.metrics.block_programs = self.scheduler.block_programs
+        self.metrics.mixed_block_programs = \
+            self.scheduler.mixed_block_programs
         # phase-split busy seconds (single decode-thread writer)
         self.metrics.prefill_busy_s = self.scheduler.prefill_wall_s
         self.metrics.decode_busy_s = self.scheduler.decode_wall_s

@@ -59,6 +59,10 @@ class ServeMetrics:
     cancelled: int = 0                 # explicit / disconnect / deadline
     deadline_misses: int = 0           # cancels whose cause was timeout_s
     gang_merges: int = 0               # cross-gang straggler merges
+    block_programs: int = 0            # decode_block calls (mirrored from
+                                       # the scheduler each engine step)
+    mixed_block_programs: int = 0      # ... whose live rows stood at more
+                                       # than one block index
     # cross-request prefix cache (repro.cache): request-level hit
     # counters accumulate per completion; bytes/evictions/nodes are
     # gauges mirrored from the store each engine step
@@ -213,6 +217,9 @@ class ServeMetrics:
             "cancelled": self.cancelled,
             "deadline_misses": self.deadline_misses,
             "gang_merges": self.gang_merges,
+            "ticks": self.ticks,
+            "block_programs": self.block_programs,
+            "mixed_block_programs": self.mixed_block_programs,
             "prefix_cache_hits": self.prefix_cache_hits,
             "prefix_cache_hit_tokens": self.prefix_cache_hit_tokens,
             "prefix_cache_evictions": self.prefix_cache_evictions,

@@ -8,8 +8,11 @@ tick advances each live gang by one block, then harvests: finished rows
 the gang is *compacted* — live rows are gathered into the next
 power-of-two batch bucket, freed slots are backfilled from the waiting
 queue at the same tick, and the old KV buffer returns to the
-``PrefixKVPool``. Compiled step shapes are therefore fixed per
-(bucket, batch-pow2, block-index) triple: after warmup no request
+``PrefixKVPool``. Where the decoder ``mixes_blocks``, a gang's rows may
+stand at different block indexes: gangs of one shape bucket merge
+whatever their blocks, and a request admitted at a boundary joins a
+live gang's vacated lanes before its first block. Compiled step shapes
+are fixed per (bucket, batch, query width): after warmup no request
 causes a recompile.
 
 Exactness: compaction relies on ``DiffusionDecoder.batch_invariant`` —
@@ -214,6 +217,9 @@ class BlockScheduler:
         self._uid = 0
         self.last_decoded_rows = 0
         self.merges = 0            # cross-gang straggler merges performed
+        self.block_programs = 0    # decode_block calls
+        # ... of which advanced live rows at more than one block index
+        self.mixed_block_programs = 0
         # observability (repro.obs) — all optional. ``tracer`` records
         # queue/decode/block spans on the request's async track plus
         # scheduler.* and decoder.block spans on this engine's thread
@@ -296,6 +302,7 @@ class BlockScheduler:
                 "batch": g.batch,
                 "live_rows": len(g.live_rows()),
                 "block_idx": g.state.block_idx,
+                "blocks": g.state.blocks.tolist(),
                 "n_blocks": g.state.n_blocks,
                 "prompt_len": g.state.prompt_len,
                 "method": g.decoder.dcfg.method,
@@ -413,10 +420,11 @@ class BlockScheduler:
                 if req.uid not in self._cancel:
                     continue
                 self._cancel.discard(req.uid)
-                gen = st.x[i, P:P + st.block_idx * K].copy()
+                bidx = int(st.blocks[i])
+                gen = st.x[i, P:P + bidx * K].copy()
                 completions.append(
                     self._make_completion(req, gen, now, cancelled=True))
-                chunks.append(BlockChunk(req.uid, st.block_idx,
+                chunks.append(BlockChunk(req.uid, bidx,
                                          np.zeros(0, np.int32), "",
                                          True, False))
                 gang.requests[i] = None
@@ -580,11 +588,13 @@ class BlockScheduler:
     # ------------------------------------------------------ merge
 
     def _merge_stragglers(self) -> None:
-        """Cross-gang merge (ROADMAP open item): gangs that sit at the
-        same (shape bucket, block index) — typically stragglers left
-        ragged by early exits, cancels, or split admissions — are fused
-        into one gang before the next ``decode_block``, so N part-full
-        block calls become one. Safe only for batch-invariant methods
+        """Cross-gang merge: gangs of one shape bucket — typically
+        stragglers left ragged by early exits, cancels, split admissions
+        or requests that arrived at different ticks — are fused into one
+        gang before the next ``decode_block``, so N part-full block
+        calls become one. Where the decoder ``mixes_blocks`` the gangs
+        may stand at different block indexes; otherwise only gangs at
+        the same block merge. Safe only for batch-invariant methods
         (per-row tokens don't depend on batching); dkv gangs are never
         touched. Merged rows restart their gang-level counters exactly
         like compaction (``take_rows``) does."""
@@ -598,7 +608,9 @@ class BlockScheduler:
             if any(r is not None and r.uid in self._preempt
                    for r in g.requests):
                 continue    # let preemption extract its row first
-            key = (st.prompt_len, st.total_len, st.block_idx)
+            key = (st.prompt_len, st.total_len)
+            if not g.decoder.mixes_blocks:
+                key += (st.block_idx,)
             groups.setdefault(key, []).append(g)
         for gs in groups.values():
             if len(gs) < 2:
@@ -674,9 +686,10 @@ class BlockScheduler:
     # ------------------------------------------------------ tick
 
     def tick(self) -> Tuple[List[BlockChunk], List[Completion]]:
-        """One scheduler round: release cancelled rows → admit →
-        advance every gang one block → harvest chunks/completions →
-        compact + backfill."""
+        """One scheduler round: release cancelled rows → admit → merge
+        (so a gang admitted at this boundary joins a live gang before
+        its first block) → advance every gang one block → harvest
+        chunks/completions → compact + backfill."""
         chunks, completions = self._apply_cancels()
         if self.prefill_only:
             # prefill pool: admit (prefill publishes chunk KV to the
@@ -688,23 +701,27 @@ class BlockScheduler:
                 self._extract_handoffs()
             self.last_decoded_rows = 0
             return chunks, completions
+        with span(self.tracer, "scheduler.admit", pid=self.pid):
+            self._admit()
         merges = self.merges
         with span(self.tracer, "scheduler.merge", pid=self.pid) as sp:
             self._merge_stragglers()
             sp.annotate(merges=self.merges - merges)
-        with span(self.tracer, "scheduler.admit", pid=self.pid):
-            self._admit()
         # rows whose decode this tick actually pays for — sampled before
         # the decode loop so occupancy isn't attributed post-compaction
         self.last_decoded_rows = self.live_rows
         for gang in self.gangs:
             size0 = self.jit_cache_size()
             st = gang.state
+            if not st.finished:
+                self.block_programs += 1
+                if len(set(st.blocks[st.live].tolist())) > 1:
+                    self.mixed_block_programs += 1
             t0_ns = time.perf_counter_ns()
             # the block on this engine's track and in a profiler
             # capture; its steps and commits are known only at the end
             with span(self.tracer, "decoder.block", pid=self.pid,
-                      batch=st.batch, live=int((~st.done).sum()),
+                      batch=st.batch, live=int(st.live.sum()),
                       block=st.block_idx,
                       prompt_len=st.prompt_len) as sp:
                 gang.decoder.decode_block(st)
@@ -783,7 +800,7 @@ class BlockScheduler:
             self._trace_admit(req)
             self.gangs.append(Gang(decoder, state, [req]))
             free -= state.batch
-        if free <= 0 or not self.waiting:
+        if not self.waiting:
             return
         # bucket the queue once per _admit (not per admitted gang — a
         # large backlog is exactly the continuous-batching regime)
@@ -820,9 +837,46 @@ class BlockScheduler:
                 break
             if not admitted:
                 break
+        self._admit_into_lanes(groups, admitted_ids)
         if admitted_ids:
             self.waiting = deque(r for r in self.waiting
                                  if id(r) not in admitted_ids)
+
+    def _admit_into_lanes(self, groups: Dict[tuple, List[ServeRequest]],
+                          admitted_ids: set) -> None:
+        """Admit waiting requests into the lanes a live gang holds but no
+        open row uses — a finished row's lane that compaction kept for
+        the padded batch, or a pad lane — where its decoder
+        ``mixes_blocks``: each new gang is merged into that gang at
+        once, so it decodes there this tick, at block 0, and no slot is
+        added. A new request then never waits for a whole gang to
+        drain."""
+        if not self.merge_gangs or self.prefill_only:
+            return
+        for g in list(self.gangs):
+            st = g.state
+            if not g.decoder.mixes_blocks or st.finished or any(
+                    r is not None and r.uid in self._preempt
+                    for r in g.requests):
+                continue
+            bucket = (st.prompt_len, g.decoder.dcfg.gen_len)
+            group = groups.get(bucket, [])
+            n_open = len(g.open_rows())
+            n = min(len(group), g.batch - n_open,
+                    self.max_gang - n_open)
+            while n > 0 and self._pad_batch(n_open + n) > g.batch:
+                n -= 1
+            if n <= 0:
+                continue
+            reqs = group[:n]
+            del group[:n]
+            admitted_ids.update(id(r) for r in reqs)
+            new = self._form_gang(g.decoder, bucket, reqs,
+                                  self._pad_batch(n))
+            self.gangs.append(new)
+            # the live gang's buffer goes back to the pool last, so the
+            # merged gang's acquire of that size finds it
+            self._merge_bin([new, g])
 
     def _group_key(self, r: ServeRequest) -> tuple:
         """Admission group: shape bucket, plus — with the prefix cache
@@ -942,8 +996,6 @@ class BlockScheduler:
         K = gang.decoder.dcfg.block_size
         P = st.prompt_len
         eos = self.cfg.eos_token_id
-        bidx = st.block_idx - 1
-        bstart = P + bidx * K
         now = time.perf_counter()
         chunks: List[BlockChunk] = []
         completions: List[Completion] = []
@@ -956,6 +1008,9 @@ class BlockScheduler:
             if req.first_block_time < 0:
                 req.first_block_time = now
             finished = st.row_finished(i)
+            # the block this row just decoded (each live row advanced one)
+            bidx = int(st.blocks[i]) - 1
+            bstart = P + bidx * K
             if bidx >= 0:   # a zero-block request decodes nothing
                 req.blocks_decoded += 1
                 toks = st.x[i, bstart:bstart + K].copy()

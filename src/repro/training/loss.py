@@ -17,8 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import softcap
-from repro.models.model import apply_model
+from repro.models.model import apply_model, head_logits
 
 
 def chunked_ce(cfg: ModelConfig, params, hidden, tokens, weights,
@@ -29,7 +28,6 @@ def chunked_ce(cfg: ModelConfig, params, hidden, tokens, weights,
 
     Returns (sum of weighted nll, sum of weighted argmax-correct).
     """
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     B, S, D = hidden.shape
     n = max(1, -(-S // chunk))
     pad = n * chunk - S
@@ -44,9 +42,7 @@ def chunked_ce(cfg: ModelConfig, params, hidden, tokens, weights,
 
     def one(c):
         h, t, w = c
-        logits = (h @ head.astype(h.dtype)).astype(jnp.float32)
-        if cfg.logit_softcap:
-            logits = softcap(logits, cfg.logit_softcap)
+        logits = head_logits(cfg, params, h)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tok_logit = jnp.take_along_axis(logits, t[..., None], axis=-1)[..., 0]
         nll = ((lse - tok_logit) * w).sum()

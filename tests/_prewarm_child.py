@@ -38,11 +38,15 @@ BUCKETS = [(len(SHORT[0]), 16), (len(LONG[0]), 8)]
 
 def drive(eng):
     """Mixed-bucket load exercising every post-admission code path that
-    could compile: queueing beyond max_slots, straggler merges, and a
-    preempt/park/resume cycle."""
+    could compile: queueing beyond max_slots, straggler merges (across
+    block indexes too), and a preempt/park/resume cycle."""
     uids, comps = [], []
-    for i in range(3):                      # staggered: forces ragged
+    uids.append(eng.submit(SHORT[0], max_tokens=16))
+    comps += eng.step()                     # a row one block ahead ...
+    for i in (1, 2):
         uids.append(eng.submit(SHORT[i], max_tokens=16))
+    comps += eng.step()                     # ... merges with these
+    for i in range(3):                      # staggered: forces ragged
         uids.append(eng.submit(LONG[i], max_tokens=8))
     comps += eng.step()                     # gangs form, stragglers next
     for i in range(3, 8):
@@ -77,6 +81,7 @@ def main():
             "prewarm_variants": warm[len(per_engine)]["variants"],
             "compile_misses": watch.misses,
             "post_warm_compiles": watch.post_warm,
+            "mixed_block_programs": eng.scheduler.mixed_block_programs,
             "host_threads": eng.metrics.host_threads,
         })
 

@@ -201,3 +201,115 @@ def test_jitted_functions_are_named_after_their_keys():
     ex = DecodeExecutor(CFG, PARAMS, make_submeshes(1)[0])
     ex.init_cache(1, 16)
     assert [fn.__name__ for fn in ex._cache_fns.values()] == ["init_cache"]
+
+
+# ------------------------------------------- rows at different blocks
+
+MIX_PROMPTS = np.random.default_rng(5).integers(0, 200, (3, 10)).astype(
+    np.int32)
+
+
+def _mixing_decoder(method, **kw):
+    kw.setdefault("use_kernels", False)
+    return DiffusionDecoder(CFG, PARAMS, DecodeConfig(
+        method=method, gen_len=32, block_size=8, window=8, tau0=0.5, **kw))
+
+
+@pytest.mark.parametrize("use_kernels", [False, True],
+                         ids=["ref", "pallas"])
+@pytest.mark.parametrize("method", ["streaming", "fast", "prefix"])
+def test_mixed_block_gang_matches_rows_alone(method, use_kernels):
+    """A gang whose rows stand at blocks 2, 1 and 0 commits, for each
+    row, exactly the tokens that row commits decoded alone — through
+    every later block, including the calls in which the first row has
+    finished and rides along as a done lane."""
+    dec = _mixing_decoder(method, use_kernels=use_kernels)
+    assert dec.mixes_blocks
+    alone = [dec.generate(MIX_PROMPTS[i:i + 1].copy()).tokens[0]
+             for i in range(3)]
+    parts = []
+    for i, ahead in enumerate((2, 1, 0)):
+        st = dec.prefill(MIX_PROMPTS[i:i + 1].copy())
+        for _ in range(ahead):
+            dec.decode_block(st)
+        parts.append((st, [0]))
+    gang = dec.merge_rows(parts)
+    assert gang.blocks.tolist() == [2, 1, 0] and gang.block_idx == 0
+    dec.decode_block(gang)
+    assert gang.block_stats[-1].row_blocks == [2, 1, 0]
+    while not gang.finished:
+        dec.decode_block(gang)
+    assert gang.block_stats[-1].row_blocks == [-1, -1, 3]
+    out = dec.finalize(gang)
+    for i in range(3):
+        assert (out.tokens[i] == alone[i]).all(), i
+
+
+def test_block_idx_assignment_puts_every_row_at_that_block():
+    """``state.block_idx = b`` puts every row at block b; reading it
+    gives the earliest row that still has a block to decode."""
+    dec = _mixing_decoder("streaming")
+    st = dec.prefill(MIX_PROMPTS[:2].copy())
+    st.block_idx = 2
+    assert st.blocks.tolist() == [2, 2] and st.block_idx == 2
+    dec.decode_block(st)
+    assert st.block_stats[-1].row_blocks == [2, 2]
+    assert st.blocks.tolist() == [3, 3]
+    st.blocks = np.array([3, 1], np.int32)
+    assert st.block_idx == 1
+    st.done[1] = True
+    assert st.block_idx == 3 and not st.finished
+    st.blocks[0] = 4
+    assert st.finished and st.row_finished(0) and st.row_finished(1)
+
+
+def test_mixed_gang_adds_no_compiled_variant():
+    """After a warm-up of uniform gangs at every block and gang size (as
+    the serving warm-up runs them), gangs mixing block indexes compile
+    nothing: the program's only static key is (batch, query width),
+    and a mixed gang runs at its earliest row's width."""
+    dec = _mixing_decoder("streaming")
+    for B in (1, 2, 3):
+        for b in range(4):
+            st = dec.prefill(MIX_PROMPTS[:B].copy())
+            st.block_idx = b
+            dec.decode_block(st)
+    warm = dec.jit_cache_size()
+    widths = {len(dec._region(10, b)[1]) for b in range(4)}
+    assert warm == 3 * len(widths) == 9
+    for ahead in ([1, 0], [3, 0], [2, 1, 0], [3, 3, 1]):
+        parts = []
+        for i, a in enumerate(ahead):
+            st = dec.prefill(MIX_PROMPTS[i:i + 1].copy())
+            for _ in range(a):
+                dec.decode_block(st)
+            parts.append((st, [0]))
+        gang = dec.merge_rows(parts)
+        while not gang.finished:
+            dec.decode_block(gang)
+    assert dec.jit_cache_size() == warm
+
+
+@pytest.mark.parametrize("kw", [
+    dict(method="dkv"), dict(method="vanilla"),
+    dict(method="streaming", prefix_cache=True),
+    dict(method="streaming", frozen_suffix=True)],
+    ids=["dkv", "vanilla", "prefix_cache", "frozen_suffix"])
+def test_methods_that_cannot_mix_say_so(kw):
+    """dkv, vanilla, the prefix-cache and the frozen-suffix refreshes
+    keep a batch at one block index, and say so; so does every method
+    on a layout whose tokens meet outside attention (MoE routing)."""
+    kw = dict(kw)
+    method = kw.pop("method")
+    dec = _mixing_decoder(method, **kw)
+    assert not dec.mixes_blocks
+    moe = get_config("tiny-moe")
+    assert not DiffusionDecoder(moe, None, DecodeConfig(
+        method="streaming")).mixes_blocks
+    if method in ("dkv", "vanilla"):
+        return
+    a = dec.prefill(MIX_PROMPTS[:1].copy())
+    dec.decode_block(a)
+    b = dec.prefill(MIX_PROMPTS[1:2].copy())
+    with pytest.raises(AssertionError, match="one block index"):
+        dec.merge_rows([(a, [0]), (b, [0])])

@@ -100,6 +100,7 @@ def test_zero_post_warm_compiles_under_mixed_load(tmp_path_factory):
     for e in rep["per_engine"]:
         assert e["requests"] == 11
         assert e["prewarm_variants"] > 0
+        assert e["mixed_block_programs"] > 0      # rows at two blocks
         assert e["post_warm_compiles"] == 0, e    # the watchdog itself
 
 

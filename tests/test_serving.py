@@ -138,25 +138,27 @@ def test_backfill_on_early_exit():
     cfg_eos = _fake_eos_cfg(gen_len=32)
     d = _dcfg("streaming", gen_len=32)
     sched = BlockScheduler(cfg_eos, PARAMS, d, max_slots=2, tokenizer=TOK)
-    for b in range(3):
-        sched.submit(PROMPTS[b], 32, 32)
-    saw_concurrent_gangs = False
-    saw_shrink = False
+    reqs = [sched.submit(PROMPTS[b], 32, 32) for b in range(3)]
+    first, late = reqs[:2], reqs[2]
+    n_blocks = 32 // d.block_size
+    freed_after = admitted_after = None   # blocks the first gang decoded
     guard = 0
     while not sched.idle and guard < 100:
         guard += 1
-        sizes = [g.batch for g in sched.gangs]
         sched.tick()
-        new_sizes = [g.batch for g in sched.gangs]
-        if len(new_sizes) >= 2:
-            saw_concurrent_gangs = True
-        if sizes and new_sizes and min(new_sizes) < max(sizes):
-            saw_shrink = True
+        decoded = max(r.blocks_decoded for r in first)
+        if freed_after is None and any(r.finish_time >= 0 for r in first):
+            freed_after = decoded
+        if admitted_after is None and late.admit_time >= 0:
+            admitted_after = decoded
     assert guard < 100
-    # the fake-EOS model exits early almost immediately: slots must have
-    # been recycled into a second concurrent gang (the third request
-    # decodes while the first gang is still live) or via gang shrink
-    assert saw_concurrent_gangs or saw_shrink
+    # the fake-EOS model exits early: a row of the first gang finishes
+    # before its last block, and the waiting request takes the freed
+    # slot at that same block boundary, after the exit
+    assert freed_after is not None and freed_after < n_blocks
+    assert admitted_after == freed_after
+    assert late.admit_time >= min(r.finish_time for r in first
+                                  if r.finish_time >= 0)
 
 
 def test_early_exit_frees_compute():
@@ -577,3 +579,105 @@ def test_merge_respects_max_gang_and_skips_dkv():
     eng2.step()
     assert eng2.scheduler.merges == 0     # ...but dkv is never merged
     eng2.run_to_completion()
+
+
+# ------------------------------------------ gangs at different blocks
+
+
+def test_gangs_at_different_blocks_merge():
+    """A gang admitted at a boundary merges with a live gang one block
+    ahead before its first block: one block program for both, each row
+    at its own block, every row's tokens those of decoding it alone."""
+    d = _dcfg("streaming", gen_len=24, early_exit=False)
+    ref = DiffusionDecoder(CFG, PARAMS, d).generate(PROMPTS.copy())
+    eng = ContinuousEngine(CFG, PARAMS, d, max_slots=4, tokenizer=TOK)
+    uids = [eng.submit(PROMPTS[i], max_tokens=24) for i in range(2)]
+    eng.step()                            # block 0 of the first two
+    uids += [eng.submit(PROMPTS[i], max_tokens=24) for i in (2, 3)]
+    eng.step()                            # admit -> merge -> one program
+    sched = eng.scheduler
+    assert len(sched.gangs) == 1 and sched.merges == 1
+    assert sorted(sched.gangs[0].state.blocks.tolist()) == [1, 1, 2, 2]
+    assert (sched.block_programs, sched.mixed_block_programs) == (2, 1)
+    comps = {c.uid: c for c in eng.run_to_completion()}
+    for i, uid in enumerate(uids):
+        assert (comps[uid].tokens == ref.tokens[i]).all(), i
+    snap = eng.metrics.snapshot()
+    assert snap["block_programs"] == sched.block_programs == 4
+    assert snap["mixed_block_programs"] == 2
+    assert snap["ticks"] == 4
+
+
+def test_request_joins_vacated_lane_on_its_first_tick():
+    """A request that arrives while a lane of a live gang is vacated is
+    admitted at the next boundary and decodes its first block in that
+    gang on that tick — no extra slot, no extra program."""
+    d = _dcfg("streaming", gen_len=24, early_exit=False)
+    ref = DiffusionDecoder(CFG, PARAMS, d).generate(PROMPTS.copy())
+    eng = ContinuousEngine(CFG, PARAMS, d, max_slots=4, pad_pow2=True,
+                           tokenizer=TOK)
+    uids = [eng.submit(PROMPTS[i], max_tokens=24) for i in range(4)]
+    eng.step()
+    sched = eng.scheduler
+    eng.cancel(uids[1])                   # its lane is vacated ...
+    late = eng.submit(PROMPTS[1], max_tokens=24)   # ... as this arrives
+    done = eng.step()
+    assert len(sched.gangs) == 1 and sched.gangs[0].batch == 4
+    assert sched.slots_used <= sched.max_slots
+    assert (sched.block_programs, sched.mixed_block_programs) == (2, 1)
+    req = next(r for r in sched.gangs[0].requests if r and r.uid == late)
+    assert req.blocks_decoded == 1
+    comps = {c.uid: c for c in done + eng.run_to_completion()}
+    assert comps[uids[1]].cancelled
+    for i, uid in ((0, uids[0]), (1, late), (2, uids[2]), (3, uids[3])):
+        assert (comps[uid].tokens == ref.tokens[i]).all(), i
+
+
+@pytest.mark.parametrize("first", [1, 3])
+def test_closed_loop_of_eight_settles_at_one_program_per_tick(first):
+    """Eight clients in a closed loop, opened as a server sees them (the
+    engine thread's first drain takes one request, or three, and the
+    rest come a tick later): the late ones merge with the rows one
+    block ahead, and every later request joins the live gang, so each
+    tick runs one block program for all eight."""
+    d = _dcfg("streaming", gen_len=32, early_exit=False)
+    sched = BlockScheduler(CFG, PARAMS, d, max_slots=8, pad_pow2=True,
+                           tokenizer=TOK)
+    rng = np.random.default_rng(1)
+    prompt = lambda: rng.integers(0, 200, 10).astype(np.int32)  # noqa
+    for _ in range(first):
+        sched.submit(prompt(), 32, 32)
+    sched.tick()
+    for _ in range(8 - first):
+        sched.submit(prompt(), 32, 32)
+    served = 0
+    for _ in range(24):
+        _, done = sched.tick()
+        for _ in done:                    # each client asks again
+            sched.submit(prompt(), 32, 32)
+        served += len(done)
+    assert served >= 40
+    assert sched.block_programs == 25     # one program a tick
+    assert sched.mixed_block_programs >= 20
+    assert sched.live_rows + len(sched.waiting) == 8
+
+
+def test_host_loop_keeps_gangs_at_one_block():
+    """The per-step host loop decodes a batch at one block index, so its
+    decoder does not mix blocks: requests that arrive a tick apart stay
+    in gangs of their own block, and every row's tokens are those of
+    ``generate`` with the same loop."""
+    d = _dcfg("streaming", gen_len=24, early_exit=False, fused=False)
+    assert not DiffusionDecoder(CFG, PARAMS, d).mixes_blocks
+    ref = DiffusionDecoder(CFG, PARAMS, d).generate(PROMPTS.copy())
+    eng = ContinuousEngine(CFG, PARAMS, d, max_slots=4, tokenizer=TOK)
+    uids = [eng.submit(PROMPTS[i], max_tokens=24) for i in range(2)]
+    eng.step()
+    uids += [eng.submit(PROMPTS[i], max_tokens=24) for i in (2, 3)]
+    eng.step()
+    sched = eng.scheduler
+    assert sorted(g.state.block_idx for g in sched.gangs) == [1, 2]
+    comps = {c.uid: c for c in eng.run_to_completion()}
+    for i, uid in enumerate(uids):
+        assert (comps[uid].tokens == ref.tokens[i]).all(), i
+    assert sched.mixed_block_programs == 0

@@ -11,6 +11,7 @@ hold the TPU library, and every test worker imports this file.
 """
 import importlib.util
 import os
+import re
 import types
 
 import jax
@@ -102,10 +103,9 @@ def no_interpret(monkeypatch):
     jax.clear_caches()
 
 
-def test_served_block_program_fits_one_chip(one_chip, no_interpret):
+def _served_block_program(one_chip):
     """chip_smoke.py's fused block program (streaming, batch 8, first
-    block, cache donated as on the chip) compiles for one v5e: it fits
-    HBM next to the params, and calls both kernels."""
+    block, cache donated as on the chip), compiled for one v5e."""
     from repro.core.decoder import DecodeConfig, DiffusionDecoder
     from repro.core.suffix import suffix_query_region
     from repro.models import init_params
@@ -133,15 +133,40 @@ def test_served_block_program_fits_one_chip(one_chip, no_interpret):
                                  block_size=cfg.block_size, block_idx=0,
                                  window=dcfg.effective_window)
     Sq = len(region.positions)
-    compiled = dec._fused_fn().lower(
+    return dec._fused_fn().lower(
         params, _sds(one_chip, (B, P + G), jnp.int32),
         _sds(one_chip, (B, P + G), jnp.bool_),
         _sds(one_chip, (B,), jnp.bool_),
         place(jax.eval_shape(lambda: init_cache(cfg, B, P + G))),
-        _sds(one_chip, (B, Sq), jnp.int32), None, None,
-        bstart=region.block_start, pstart=0).compile()
+        _sds(one_chip, (B, Sq), jnp.int32), _sds(one_chip, (B,), jnp.int32),
+        None, None, prefix=None, pstart=0).compile()
+
+
+def test_served_block_program_fits_one_chip(one_chip, no_interpret):
+    """The served block program compiles for one v5e: it fits HBM next
+    to the params, and calls both kernels."""
+    compiled = _served_block_program(one_chip)
     assert _kernels(compiled) == {"block_attention", "confidence_argmax"}
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert used < 15.75 * 2**30
+
+
+def test_served_block_program_has_no_loop_besides_its_own(one_chip,
+                                                          no_interpret):
+    """The served block program's top level runs two loops: the
+    refresh's scan over the layers and the denoise ``while_loop``, in
+    that order. Each row's block is picked by selects, not by per-row
+    gathers or scatters, which the TPU compiler runs as serial loops of
+    their own (and which would stand after the denoise loop, where
+    ``bench/spans.py`` looks for it as the last top-level loop)."""
+    text = _served_block_program(one_chip).as_text()
+    entry = re.search(r"^ENTRY .*?^}", text, re.S | re.M).group(0)
+    loops = re.findall(r"^\s*(%\S+) = .* while\(.*body=%?([\w.\-]+)",
+                       entry, re.M)
+    assert len(loops) == 2, loops
+    # the denoise loop's body holds the steps' own scan over the layers
+    body = re.search(r"^%?" + re.escape(loops[-1][1]) + r" .*?^}", text,
+                     re.S | re.M).group(0)
+    assert " while(" in body
