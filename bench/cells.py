@@ -1,7 +1,9 @@
 """Finds a cell's parts by name: ``BENCHMARK.json`` at the root of the
-checkout, the configuration file it names, ``bench/traffic/<mix>.json``
-and one reader per metric, ``bench/metrics/<metric>.py``. Adding a
-configuration, a mix or a metric is adding a file and an entry."""
+checkout, the configuration file it names, the model family that file's
+``reference`` key names (``bench/families/<family>.py``),
+``bench/traffic/<mix>.json`` and one reader per metric,
+``bench/metrics/<metric>.py``. Adding a configuration, a family, a mix
+or a metric is adding a file and an entry."""
 from __future__ import annotations
 
 import dataclasses
@@ -83,18 +85,20 @@ def program_overrides(config: dict) -> Dict[str, object]:
 
 def model_dims(config: dict) -> dict:
     """Sizes the FLOP and byte functions and the reference use, in one
-    vocabulary whatever the source's key names."""
+    vocabulary whatever the source's key names: the method's here, the
+    model's from the ``dims`` of the family that the configuration's
+    ``reference`` key names (``bench/families/``), and ``family``, that
+    name."""
+    from bench import families      # which imports this module
     o = program_overrides(config)
     eng = config["engine"]
-    return {"d": o["d_model"], "heads": o["n_heads"],
-            "kv_heads": o["n_kv_heads"], "head_dim": o["head_dim"],
-            "d_ff": o["d_ff"], "vocab": o["vocab_size"],
-            "layers": o["n_layers"], "block": o["block_size"],
-            "window": eng["window"], "rope_theta": o["rope_theta"],
-            "norm_eps": o["norm_eps"], "mask_id": o["mask_token_id"],
-            "eos_id": o["eos_token_id"],
-            "dtype_bytes": {"float32": 4, "bfloat16": 2}[o["dtype"]],
-            "tau0": eng["tau0"], "alpha": eng["alpha"]}
+    name = config["reference"]
+    return dict(families.load(name).dims(config), family=name,
+                layers=o["n_layers"], block=o["block_size"],
+                window=eng["window"], mask_id=o["mask_token_id"],
+                eos_id=o["eos_token_id"],
+                dtype_bytes={"float32": 4, "bfloat16": 2}[o["dtype"]],
+                tau0=eng["tau0"], alpha=eng["alpha"])
 
 
 def peaks(device_kind: str, bench_dir: str = BENCH_DIR) -> dict:

@@ -2,7 +2,8 @@
 rows of the blocks delivered in the window (block-start refresh, the
 denoise steps, the head over the block rows; pad rows left out), over
 the window times the chip's peak. Steps per block from the program's
-counters; FLOPs from bench/flops.py."""
+counters; FLOPs from bench/flops.py and the model family's counts
+(bench/families/)."""
 from bench import flops
 from bench.metrics._common import steps_per_block
 

@@ -1,13 +1,7 @@
-"""Plain reference of the served model and decoding method, in
-``jax.numpy``, imported by nothing of the program.
-
-Model (``dense_gqa``): the LLaDA / Dream decoder layer. Token
-embedding; per layer pre-RMSNorm, grouped-query attention with
-rotary position embedding (rotate-half, base ``rope_theta``) over the
-keys, bidirectional, scale 1/sqrt(head_dim), output projection and
-residual; pre-RMSNorm SwiGLU MLP and residual; a final RMSNorm and
-the LM head. Query head h reads KV head h // (heads / kv_heads). RMSNorm
-gains are stored as offsets from 1 (see ``bench/weights.py``).
+"""Plain reference of the served decoding method, in ``jax.numpy``,
+imported by nothing of the program. The model's pass is the family's
+that ``m["family"]`` names (``bench/families/``): its tokens' vectors,
+its layer and its head statistics.
 
 Method (``streaming``, Streaming-dLLM): the generation is decoded in
 blocks of ``block`` tokens. At a block's start, one pass over
@@ -43,7 +37,7 @@ import functools
 
 import numpy as np
 
-NEG = -1e30
+from bench import families
 
 # (matmul precision, compute type) by name: the reference at the
 # configurations' stated precision, float32 at the default matmul
@@ -66,85 +60,6 @@ def query_positions(m: dict, prompt_len: int, gen_len: int, b: int):
     return np.asarray(pos, np.int32)
 
 
-def _rms(x, w, eps):
-    import jax.numpy as jnp
-    x32 = x.astype(jnp.float32)
-    y = x32 / jnp.sqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
-
-
-def _rope(x, pos, theta):
-    import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) * 2
-                          / x.shape[-1])
-    ang = pos.astype(jnp.float32)[..., None] * inv       # (R, S, half)
-    c, s = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(
-        jnp.float32)
-    return jnp.concatenate([a * c - b * s, a * s + b * c], -1).astype(
-        x.dtype)
-
-
-def _attend(q, kk, vv, valid, hd, chunk=512):
-    """Softmax attention of queries ``q`` (R, S, Hkv, g, hd) over keys
-    (R, Sk, Hkv, hd), in query chunks of ``chunk`` so that long prompts
-    fit."""
-    import jax
-    import jax.numpy as jnp
-    outs = []
-    for c in range(0, q.shape[1], chunk):
-        s = jnp.einsum("rqhgd,rkhd->rhgqk", q[:, c:c + chunk],
-                       kk).astype(jnp.float32) / np.sqrt(hd)
-        s = jnp.where(valid[:, None, None, None, :], s, NEG)
-        a = jax.nn.softmax(s, -1).astype(q.dtype)
-        outs.append(jnp.einsum("rhgqk,rkhd->rqhgd", a, vv))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, 1)
-
-
-def _layer(m, lw, x, pos, key_k, key_v, key_valid, dtype):
-    """One layer (weights ``lw``, cast to ``dtype`` where used) over
-    queries ``x`` (R, S, d); keys are [``key_k``, ``key_v`` (given, may
-    be None) | the queries' own], masked by ``key_valid`` (R, S_keys).
-    Returns (x, own k, own v)."""
-    import jax
-    import jax.numpy as jnp
-    R, S, _ = x.shape
-    H, Hkv, hd = m["heads"], m["kv_heads"], m["head_dim"]
-    w = jax.tree.map(lambda a: a.astype(dtype), lw)
-    mix, f = w["mixer"], w["ffn"]
-    h = _rms(x, w["norm1"], m["norm_eps"])
-    q = _rope(jnp.einsum("rsd,dhk->rshk", h, mix["wq"]), pos,
-              m["rope_theta"])
-    k = _rope(jnp.einsum("rsd,dhk->rshk", h, mix["wk"]), pos,
-              m["rope_theta"])
-    v = jnp.einsum("rsd,dhk->rshk", h, mix["wv"])
-    kk = k if key_k is None else jnp.concatenate([key_k, k], 1)
-    vv = v if key_v is None else jnp.concatenate([key_v, v], 1)
-    o = _attend(q.reshape(R, S, Hkv, H // Hkv, hd), kk, vv, key_valid, hd)
-    x = x + jnp.einsum("rshk,hkd->rsd", o.reshape(R, S, H, hd), mix["wo"])
-    h2 = _rms(x, w["norm2"], m["norm_eps"])
-    y = jax.nn.silu(jnp.einsum("rsd,df->rsf", h2, f["w_gate"])) \
-        * jnp.einsum("rsd,df->rsf", h2, f["w_up"])
-    return x + jnp.einsum("rsf,fd->rsd", y, f["w_down"]), k, v
-
-
-def _head_stats(m, p, x, probe, dtype):
-    """Block logits (float32, [MASK] banned) reduced per position: the
-    confidence, the top token, the top logit and the logits of the
-    ``probe`` tokens (R, K, n)."""
-    import jax
-    import jax.numpy as jnp
-    h = _rms(x, p["out_norm"].astype(dtype), m["norm_eps"])
-    z = jnp.einsum("rkd,dv->rkv", h,
-                   p["lm_head"].astype(dtype)).astype(jnp.float32)
-    z = z.at[..., m["mask_id"]].set(NEG)
-    top = jnp.max(z, -1)
-    conf = jnp.exp(top - jax.scipy.special.logsumexp(z, -1))
-    arg = jnp.argmax(z, -1).astype(jnp.int32)
-    return conf, arg, top, jnp.take_along_axis(z, probe, -1)
-
-
 @functools.lru_cache(maxsize=None)
 def _fns(mkey, numerics):
     """Jitted block-start pass and step for one set of dims, in one of
@@ -153,19 +68,20 @@ def _fns(mkey, numerics):
     import jax
     import jax.numpy as jnp
     m = dict(mkey)
+    fam = families.load(m["family"])
     K = m["block"]
     precision, dtype_name = NUMERICS[numerics]
     dtype = jnp.dtype(dtype_name)
 
     def refresh_row(p, toks, pos, valid, boff, probe):
         def body(x, lw):
-            x, k, v = _layer(m, lw, x, pos[None], None, None, valid[None],
-                             dtype)
+            x, k, v = fam.layer(m, lw, x, pos[None], None, None,
+                                valid[None], pos[None], dtype)
             return x, (k[0], v[0])
-        x0 = p["embed"][toks][None].astype(dtype)
-        x, (ks, vs) = jax.lax.scan(body, x0, p["scan"][0])
+        x0 = fam.token_vectors(m, p, toks[None], dtype)
+        x, (ks, vs) = jax.lax.scan(body, x0, fam.layers(p))
         blk = jax.lax.dynamic_slice_in_dim(x, boff, K, 1)
-        stats = _head_stats(m, p, blk, probe[None], dtype)
+        stats = fam.head_stats(m, p, blk, probe[None], dtype)
         return tuple(s[0] for s in stats), ks, vs
 
     def refresh(p, toks, pos, valid, boff, probe):
@@ -175,16 +91,17 @@ def _fns(mkey, numerics):
         # (R, L, S, Hkv, hd) -> (L, R, S, Hkv, hd)
         return stats, ks.swapaxes(0, 1), vs.swapaxes(0, 1)
 
-    def step(p, ks, vs, key_valid, toks, pos, q_valid, probe):
+    def step(p, ks, vs, key_valid, key_pos, toks, pos, q_valid, probe):
         valid = jnp.concatenate([key_valid, q_valid], 1)
+        kpos = jnp.concatenate([key_pos, pos], 1)
 
         def body(x, a):
             lw, k, v = a
-            x, _, _ = _layer(m, lw, x, pos, k, v, valid, dtype)
+            x, _, _ = fam.layer(m, lw, x, pos, k, v, valid, kpos, dtype)
             return x, None
-        x0 = p["embed"][toks].astype(dtype)
-        x, _ = jax.lax.scan(body, x0, (p["scan"][0], ks, vs))
-        return _head_stats(m, p, x[:, :K], probe, dtype)
+        x0 = fam.token_vectors(m, p, toks, dtype)
+        x, _ = jax.lax.scan(body, x0, (fam.layers(p), ks, vs))
+        return fam.head_stats(m, p, x[:, :K], probe, dtype)
 
     def scoped(fn):
         def call(*a):
@@ -269,7 +186,8 @@ def replay(m: dict, params, prompts: np.ndarray, served=None,
         valid[:, :n] = True
         key_valid = np.zeros((R, T + 1), bool)
         key_valid[:, :bs] = True
-        args = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(valid),
+        key_pos = jnp.asarray(pos)  # positions of the keys the step reads
+        args = (jnp.asarray(toks), key_pos, jnp.asarray(valid),
                 jnp.int32(bs))
         mine = jnp.asarray(served[:, b * K:(b + 1) * K, None])
         if control:
@@ -305,7 +223,7 @@ def replay(m: dict, params, prompts: np.ndarray, served=None,
             qp = np.zeros((R, q_max), np.int32)
             qv = np.zeros((R, q_max), bool)
             qt[:, :sq], qp[:, :sq], qv[:, :sq] = x[:, qpos], qpos, True
-            sargs = (jnp.asarray(key_valid), jnp.asarray(qt),
+            sargs = (jnp.asarray(key_valid), key_pos, jnp.asarray(qt),
                      jnp.asarray(qp), jnp.asarray(qv))
             mine = jnp.asarray(served[:, b * K:(b + 1) * K, None])
             if control:

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from bench import cells, correct
+from bench.tests.test_bench_families import assert_tree_is_the_programs
 
 ROOT = cells.ROOT
 
@@ -55,7 +56,10 @@ def test_every_cell_finds_its_parts():
         for m in cell.end_to_end + cell.per_layer:
             assert callable(cells.load_reader(m["name"]))
         dims = cells.model_dims(cell.config)
+        assert dims["family"] == cell.config["reference"]
         assert dims["heads"] % dims["kv_heads"] == 0
+        # the weights the benchmark makes fit the program's tree
+        assert_tree_is_the_programs(cell.config)
         # every limit names a number the check computes
         limits = set(cell.config["limits"])
         assert limits and limits <= set(correct.readings(np.zeros(1)))

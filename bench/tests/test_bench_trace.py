@@ -77,9 +77,10 @@ def test_roofline_reader_by_hand():
     import types
 
     from bench import cells, flops
-    m = {"d": 8, "heads": 4, "kv_heads": 2, "head_dim": 4, "d_ff": 16,
-         "vocab": 10, "layers": 2, "block": 4, "window": 4,
-         "dtype_bytes": 4}
+    from bench.families import dense_gqa
+    m = {"family": "dense_gqa", "d": 8, "heads": 4, "kv_heads": 2,
+         "head_dim": 4, "d_ff": 16, "vocab": 10, "layers": 2, "block": 4,
+         "window": 4, "dtype_bytes": 4}
     peaks = {"flops_per_s": 1e9, "hbm_bytes_per_s": 1e8}
     ev = {"device": {"/device:TPU:0": [
               ("%block_attention.1 = f32[2] custom-call(q)", 10, 600_000),
@@ -94,7 +95,8 @@ def test_roofline_reader_by_hand():
                                 gen_len=12)
     want = 0.0
     for count, sq, skv in flops.block_passes(m, 6, 12, 1, 4.0):
-        want += count * 2 * 2 * max(flops.attention_flops(m, sq, skv) / 1e9,
-                                    flops.attention_bytes(m, sq, skv) / 1e8)
+        want += count * 2 * 2 * max(
+            dense_gqa.attention_flops(m, sq, skv) / 1e9,
+            dense_gqa.attention_bytes(m, sq, skv) / 1e8)
     got = cells.load_reader("block_attention_roofline")(run)
     assert got == pytest.approx(100.0 * want / 1e-3)
